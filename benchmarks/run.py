@@ -38,6 +38,7 @@ import dataclasses
 import json
 import os
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.quantities import US_PER_S
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
@@ -548,6 +549,7 @@ def main(argv=None) -> None:
                          "500-site/100k + saturated pair + "
                          "5000-site/1M sweep)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name in args.bench or BENCHES:
         fn = BENCHES[name][0]

@@ -158,7 +158,7 @@ def audit_kernel(spec) -> dict[str, Any]:
 
 def _audit_oracle(spec) -> dict[str, Any]:
     """Runtime dtype + bit-identity checks for a sim kernel's oracle."""
-    from jax.experimental import enable_x64
+    import jax
 
     ref = spec.load_ref()
     kernel = spec.load_kernel()
@@ -172,7 +172,7 @@ def _audit_oracle(spec) -> dict[str, Any]:
         np.asarray(r).dtype == np.float64 or np.asarray(r).ndim == 0
         for r in ref_flat)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         k_out = kernel(*args64, **kwargs, interpret=True)
     k_flat = (k_out if isinstance(k_out, tuple) else (k_out,))
     identical = len(k_flat) == len(ref_flat) and all(

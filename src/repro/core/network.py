@@ -149,9 +149,13 @@ class NetworkEngine:
         # routes' per-event member-union + eta-scan work; identically 0
         # on the batched backend, whose rerate only marks dirty links);
         # flush_passes / flush_slots — fused passes and the slots they
-        # re-rated (at most one pass per drained instant).
+        # re-rated (at most one pass per drained instant); flush_kernel /
+        # flush_host — passes that re-rated slots on the compiled
+        # event_engine kernel / on the host (float64 oracle or the Pallas
+        # interpreter), so a run shows which route its flushes took.
         self.stats = {"rerate_calls": 0, "rerate_slots": 0,
-                      "flush_passes": 0, "flush_slots": 0}
+                      "flush_passes": 0, "flush_slots": 0,
+                      "flush_kernel": 0, "flush_host": 0}
         self._pair_paths: Optional[np.ndarray] = None   # lazy (S, S, depth)
         # per-destination (link idx, validity) slices of the path tensor,
         # cached on first use: topology is static, only link shares move
@@ -476,6 +480,8 @@ class NetworkEngine:
         if self._use_kernel or self.backend == "device-interpret":
             self._dirty_links.clear()
             self.stats["flush_slots"] += self.n_active
+            self.stats["flush_kernel" if self._use_kernel
+                       else "flush_host"] += 1
             out = self._flush_op(self.path, self.rem, self.rate, self.eta,
                                  self.link_bw, self.link_act, now,
                                  backend="pallas" if self._use_kernel
@@ -497,6 +503,7 @@ class NetworkEngine:
         self._dirty_links.clear()
         if merged:
             self.stats["flush_slots"] += len(merged)
+            self.stats["flush_host"] += 1
             if len(merged) <= 8:
                 # scalar fast path: same IEEE-double math as the ref pass
                 # (Python floats are f64), skipping the fancy-index

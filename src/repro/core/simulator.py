@@ -169,7 +169,13 @@ class SimResult:
 
     @property
     def avg_job_time(self) -> float:
-        return sum(r.job_time for r in self.records) / max(1, len(self.records))
+        # plain left-to-right float addition: the builtin sum() compensates
+        # its rounding from Python 3.12 on, which moves the mean by ulps
+        # and breaks the bit-exact goldens
+        total = 0.0
+        for r in self.records:
+            total += r.job_time
+        return total / max(1, len(self.records))
 
     @property
     def avg_inter_comms(self) -> float:

@@ -8,16 +8,24 @@ is re-rated (gather-min of per-link fair shares along its path, as in
 etas yields the next NET wake-up — one device call per drained instant
 instead of one per event.
 
-Layout matches ``net_rerate``: the path matrix is transposed to
-``(max_links, slots)`` so the slot axis rides the lanes (padded to a lane
-multiple) and the small static level axis is unrolled in the kernel; the
-slot-state rows (rem/rate/eta) are ``(1, slots)`` VMEM rows, link
-bandwidth/occupancy are ``(1, links)`` rows, ``now`` sits in SMEM. One
-program sees the whole batch — even 100k slots is a few MB of VMEM.
+Layout matches ``net_rerate``: the jitted wrapper computes the per-link
+fair shares, prepends an ``inf`` sentinel for the path matrix's ``-1``
+padding and gathers them into a ``(max_links, slots)`` share plane (a
+1-D gather inside the kernel does not lower on TPU); the slot axis rides
+the lanes (padded to a lane multiple) and the small level axis the
+sublanes. The kernel takes the min over levels, reconstructs remaining
+bytes, recomputes every eta and reduces to the earliest one. The
+slot-state rows (rem/rate/eta) are ``(1, slots)`` VMEM rows, ``now`` sits
+in SMEM. One program sees the whole batch — even 100k slots is a few MB
+of VMEM.
 
-Interpret mode under ``jax.experimental.enable_x64`` computes in float64
-and is bit-identical to ``ref.event_engine_ref`` (where/multiply/divide/
-min are exact IEEE ops) — the contract the jaxpr auditor and
+Times may be absolute or relative to the flush instant: the ops wrapper
+passes ``eta - now`` and ``now = 0`` so that float32 on the chip resolves
+the gap to the next completion, not the absolute clock.
+
+Interpret mode under ``jax.enable_x64`` computes in float64 and is
+bit-identical to ``ref.event_engine_ref`` (where/multiply/divide/min are
+exact IEEE ops) — the contract the jaxpr auditor and
 ``tests/test_kernels.py`` pin.
 """
 
@@ -36,55 +44,51 @@ _LANES = 128
 _SUBLANES = 8
 
 
-def _event_flush_kernel(path_ref, rem_ref, rate_ref, eta_ref, bw_ref,
-                        act_ref, now_ref, rem_out, rate_out, eta_out,
-                        eta_min_ref, *, levels: int):
-    share = bw_ref[0, :] / jnp.maximum(1.0, act_ref[0, :])     # (links,)
-    rate_new = None
-    has_link = None
-    for lvl in range(levels):                                   # static unroll
-        idx = path_ref[lvl, :]                                  # (slots,)
-        valid = idx >= 0
-        sh = jnp.where(valid, jnp.take(share, jnp.maximum(idx, 0)), jnp.inf)
-        rate_new = sh if rate_new is None else jnp.minimum(rate_new, sh)
-        has_link = valid if has_link is None else has_link | valid
-    rate_new = jnp.where(has_link, rate_new, 0.0)
+def _event_flush_kernel(share_ref, rem_ref, rate_ref, eta_ref, now_ref,
+                        rem_out, rate_out, eta_out, eta_min_ref):
+    # min fair share over each slot's path; all-padding columns reduce to
+    # the bare inf sentinel: dead, rate 0
+    rate_new = jnp.min(share_ref[...], axis=0, keepdims=True)   # (1, slots)
+    rate_new = jnp.where(rate_new < jnp.inf, rate_new, 0.0)
     now = now_ref[0, 0]
-    rate_old = rate_ref[0, :]
+    rate_old = rate_ref[...]
     carried = rate_old > 0.0
     # mask dead slots' inf etas before the multiply (no 0*inf NaNs)
-    eta_c = jnp.where(carried, eta_ref[0, :], 0.0)
+    eta_c = jnp.where(carried, eta_ref[...], 0.0)
     rem_now = jnp.maximum(
-        jnp.where(carried, rate_old * (eta_c - now), rem_ref[0, :]), 0.0)
+        jnp.where(carried, rate_old * (eta_c - now), rem_ref[...]), 0.0)
     live = rate_new > 0.0
     eta_new = jnp.where(live, now + rem_now / jnp.where(live, rate_new, 1.0),
                         jnp.inf)
-    rem_out[0, :] = rem_now
-    rate_out[0, :] = rate_new
-    eta_out[0, :] = eta_new
+    rem_out[...] = rem_now
+    rate_out[...] = rate_new
+    eta_out[...] = eta_new
     eta_min_ref[0, 0] = jnp.min(eta_new)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _flush_call(path, rem, rate, eta, link_bw, link_act, now, *,
                 interpret: bool):
-    levels, slots = path.shape
+    slots = path.shape[1]
     dtype = rem.dtype
-    kernel = functools.partial(_event_flush_kernel, levels=levels)
+    # per-link shares behind an inf cell at index 0, where the -1 padding
+    # lands once every id is shifted by one; a flat gather keeps every
+    # intermediate 2-D
+    share = jnp.concatenate([jnp.full((1,), jnp.inf, dtype),
+                             link_bw / jnp.maximum(1.0, link_act)])
+    shares = jnp.take(share, (path + 1).reshape(-1),
+                      mode="clip").reshape(path.shape)
+    row = jax.ShapeDtypeStruct((1, slots), dtype)
     rem_now, rate_new, eta_new, eta_min = pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 6
+        _event_flush_kernel,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 4
         + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 3
         + [pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_shape=[jax.ShapeDtypeStruct((1, slots), dtype),
-                   jax.ShapeDtypeStruct((1, slots), dtype),
-                   jax.ShapeDtypeStruct((1, slots), dtype),
-                   jax.ShapeDtypeStruct((1, 1), dtype)],
+        out_shape=[row, row, row, jax.ShapeDtypeStruct((1, 1), dtype)],
         interpret=interpret,
-    )(path, rem.reshape(1, slots), rate.reshape(1, slots),
-      eta.reshape(1, slots), link_bw.reshape(1, -1),
-      link_act.reshape(1, -1), now.reshape(1, 1))
+    )(shares, rem.reshape(1, slots), rate.reshape(1, slots),
+      eta.reshape(1, slots), now.reshape(1, 1))
     return rem_now[0], rate_new[0], eta_new[0], eta_min[0, 0]
 
 
@@ -102,21 +106,15 @@ def event_engine_kernel(path, rem, rate, eta, link_bw, link_act, now, *,
         return z, z, z, jnp.asarray(jnp.inf, rem.dtype)
     pad_s = (-slots) % _LANES
     pad_l = (-levels) % _SUBLANES
-    # transpose so slots ride the lanes; padded slots are all -1 path rows
-    # with zeroed state — they re-rate to 0 and an inf eta, dropping out
-    # of the min
+    # transpose so slots ride the lanes; padded slots are all -1 path
+    # columns with zeroed state — they re-rate to 0 and an inf eta,
+    # dropping out of the min
     path_t = jnp.pad(path.T, ((0, pad_l), (0, pad_s)), constant_values=-1)
     rem_p = jnp.pad(jnp.asarray(rem), (0, pad_s))
     rate_p = jnp.pad(jnp.asarray(rate, rem.dtype), (0, pad_s))
     eta_p = jnp.pad(jnp.asarray(eta, rem.dtype), (0, pad_s))
-    nlinks = link_bw.shape[0]
-    pad_k = (-nlinks) % _LANES
-    # padded links get bw=1/act=1 (share 1.0); no real path row indexes them
-    bw_p = jnp.pad(jnp.asarray(link_bw, rem.dtype), (0, pad_k),
-                   constant_values=1.0)
-    act_p = jnp.pad(jnp.asarray(link_act, rem.dtype), (0, pad_k),
-                    constant_values=1.0)
-    now = jnp.asarray(now, rem.dtype)
     rem_now, rate_new, eta_new, eta_min = _flush_call(
-        path_t, rem_p, rate_p, eta_p, bw_p, act_p, now, interpret=interpret)
+        path_t, rem_p, rate_p, eta_p, jnp.asarray(link_bw, rem.dtype),
+        jnp.asarray(link_act, rem.dtype), jnp.asarray(now, rem.dtype),
+        interpret=interpret)
     return rem_now[:slots], rate_new[:slots], eta_new[:slots], eta_min

@@ -31,38 +31,35 @@ def event_engine(path, rem, rate, eta, link_bw, link_act, now, *,
     See :func:`.ref.event_engine_ref` for the argument contract. Returns
     a host ``(rem_now, rate_new, eta_new, eta_min)`` tuple regardless of
     backend.
+
+    The kernel routes see times relative to the flush instant: ``eta -
+    now`` is taken and ``now`` added back on the host in float64, so the
+    float32 chip resolves the gap to each completion instead of the
+    absolute clock (at a 1e6 s clock float32 steps by 0.06 s). In float64
+    the shift is exact — ``(eta - now) - 0`` is ``eta - now`` and
+    rounding is monotone, so ``now + min(x) == min(now + x)`` — and the
+    interpret route stays bit-identical to the oracle.
     """
-    if backend in ("auto", "pallas", "interpret"):
-        import jax
+    if backend != "numpy":
+        import jax  # deferred: the oracle route needs no jax
 
-        if backend == "pallas" or (backend == "auto"
-                                   and jax.default_backend() == "tpu"):
-            from .kernel import event_engine_kernel
+        if backend == "auto":
+            backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
+    if backend in ("pallas", "interpret"):
+        from .kernel import event_engine_kernel
+        interpret = backend == "interpret"
+        dtype = np.float64 if interpret else np.float32
+        eta_rel = np.asarray(eta, np.float64) - now
+        with jax.enable_x64(interpret):
             out = event_engine_kernel(
-                np.asarray(path, np.int32), np.asarray(rem, np.float32),
-                np.asarray(rate, np.float32), np.asarray(eta, np.float32),
-                np.asarray(link_bw, np.float32),
-                np.asarray(link_act, np.float32), np.float32(now))
-            rem_now, rate_new, eta_new, eta_min = out
-            return (np.asarray(rem_now, np.float64),
-                    np.asarray(rate_new, np.float64),
-                    np.asarray(eta_new, np.float64), float(eta_min))
-        if backend == "interpret":
-            from jax.experimental import enable_x64
-
-            from .kernel import event_engine_kernel
-            with enable_x64():
-                out = event_engine_kernel(
-                    np.asarray(path, np.int32), np.asarray(rem, np.float64),
-                    np.asarray(rate, np.float64), np.asarray(eta, np.float64),
-                    np.asarray(link_bw, np.float64),
-                    np.asarray(link_act, np.float64), np.float64(now),
-                    interpret=True)
-            rem_now, rate_new, eta_new, eta_min = out
-            return (np.asarray(rem_now, np.float64),
-                    np.asarray(rate_new, np.float64),
-                    np.asarray(eta_new, np.float64), float(eta_min))
-        backend = "numpy"
+                np.asarray(path, np.int32), np.asarray(rem, dtype),
+                np.asarray(rate, dtype), eta_rel.astype(dtype),
+                np.asarray(link_bw, dtype), np.asarray(link_act, dtype),
+                dtype(0.0), interpret=interpret)
+        rem_now, rate_new, eta_new, eta_min = out
+        return (np.asarray(rem_now, np.float64),
+                np.asarray(rate_new, np.float64),
+                now + np.asarray(eta_new, np.float64), now + float(eta_min))
     if backend != "numpy":
         raise ValueError(f"unknown event_engine backend {backend!r} "
                          "(want 'auto'|'pallas'|'interpret'|'numpy')")

@@ -7,17 +7,19 @@ schedule the next NET wake-up. At 100k concurrent transfers that is a
 (slots x path) gather-min plus a masked min-reduction — one VPU-shaped
 pass, no MXU.
 
-Layout: the path matrix is transposed to ``(max_links, slots)`` so the
-slot axis lands on lanes, padded to a lane multiple; the (small, static)
-link-level axis is unrolled in the kernel. Link shares are computed once
-per call from the ``(1, links)`` bandwidth/occupancy rows and gathered per
-level with ``jnp.take``. A single program sees the whole batch: even at
-100k slots the operands are ~2 MB, well under VMEM.
+Layout: the jitted wrapper computes the per-link shares, prepends an
+``inf`` sentinel for the path matrix's ``-1`` padding and gathers them
+into a ``(max_links, slots)`` share plane (a 1-D gather inside the kernel
+does not lower on TPU), so the slot axis lands on lanes, padded to a lane
+multiple, and the small link-level axis on sublanes. The kernel takes
+the min over levels and scans for the next completion. A single program
+sees the whole batch: even at 100k slots the operands are ~2 MB, well
+under VMEM.
 
 Interpret mode runs the same kernel eagerly with jnp on CPU; under
-``jax.experimental.enable_x64`` it computes in float64 and is then
-bit-identical to ``ref.net_rerate_ref`` (divide/min are exact IEEE ops) —
-that is the contract ``tests/test_kernels.py`` pins.
+``jax.enable_x64`` it computes in float64 and is then bit-identical to
+``ref.net_rerate_ref`` (divide/min are exact IEEE ops) — that is the
+contract ``tests/test_kernels.py`` pins.
 """
 
 from __future__ import annotations
@@ -35,41 +37,39 @@ _LANES = 128
 _SUBLANES = 8
 
 
-def _rerate_scan_kernel(path_ref, rem_ref, bw_ref, act_ref, now_ref,
-                        rate_ref, eta_ref, *, levels: int):
-    share = bw_ref[0, :] / jnp.maximum(1.0, act_ref[0, :])     # (links,)
-    rate = None
-    has_link = None
-    for lvl in range(levels):                                   # static unroll
-        idx = path_ref[lvl, :]                                  # (slots,)
-        valid = idx >= 0
-        sh = jnp.where(valid, jnp.take(share, jnp.maximum(idx, 0)), jnp.inf)
-        rate = sh if rate is None else jnp.minimum(rate, sh)
-        has_link = valid if has_link is None else has_link | valid
-    rate = jnp.where(has_link, rate, 0.0)
-    rate_ref[0, :] = rate
+def _rerate_scan_kernel(share_ref, rem_ref, now_ref, rate_ref, eta_ref):
+    # min fair share over each slot's path; all-padding columns reduce to
+    # the bare inf sentinel and get rate 0
+    rate = jnp.min(share_ref[...], axis=0, keepdims=True)       # (1, slots)
+    rate = jnp.where(rate < jnp.inf, rate, 0.0)
+    rate_ref[...] = rate
     now = now_ref[0, 0]
     # live slots only: padding rows have rate 0 and drop out of the min
-    eta = jnp.where(rate > 0.0, now + rem_ref[0, :] / rate, jnp.inf)
+    eta = jnp.where(rate > 0.0, now + rem_ref[...] / rate, jnp.inf)
     eta_ref[0, 0] = jnp.min(eta)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _rerate_call(path, rem, link_bw, link_act, now, *, interpret: bool):
-    levels, slots = path.shape
+    slots = path.shape[1]
     dtype = rem.dtype
-    kernel = functools.partial(_rerate_scan_kernel, levels=levels)
+    # per-link shares behind an inf cell at index 0, where the -1 padding
+    # lands once every id is shifted by one; a flat gather keeps every
+    # intermediate 2-D
+    share = jnp.concatenate([jnp.full((1,), jnp.inf, dtype),
+                             link_bw / jnp.maximum(1.0, link_act)])
+    shares = jnp.take(share, (path + 1).reshape(-1),
+                      mode="clip").reshape(path.shape)
     rate, eta = pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 4
+        _rerate_scan_kernel,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 2
         + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=[jax.ShapeDtypeStruct((1, slots), dtype),
                    jax.ShapeDtypeStruct((1, 1), dtype)],
         interpret=interpret,
-    )(path, rem.reshape(1, slots), link_bw.reshape(1, -1),
-      link_act.reshape(1, -1), now.reshape(1, 1))
+    )(shares, rem.reshape(1, slots), now.reshape(1, 1))
     return rate[0], eta[0, 0]
 
 
@@ -90,14 +90,8 @@ def net_rerate_kernel(path, rem, link_bw, link_act, now, *,
     # come out with rate 0, which the eta scan ignores
     path_t = jnp.pad(path.T, ((0, pad_l), (0, pad_s)), constant_values=-1)
     rem_p = jnp.pad(rem, (0, pad_s))
-    nlinks = link_bw.shape[0]
-    pad_k = (-nlinks) % _LANES
-    # padded links get bw=1/act=1 (share 1.0); no real path row indexes them
-    bw_p = jnp.pad(jnp.asarray(link_bw, rem.dtype), (0, pad_k),
-                   constant_values=1.0)
-    act_p = jnp.pad(jnp.asarray(link_act, rem.dtype), (0, pad_k),
-                    constant_values=1.0)
-    now = jnp.asarray(now, rem.dtype)
-    rate, eta = _rerate_call(path_t, rem_p, bw_p, act_p, now,
+    rate, eta = _rerate_call(path_t, rem_p, jnp.asarray(link_bw, rem.dtype),
+                             jnp.asarray(link_act, rem.dtype),
+                             jnp.asarray(now, rem.dtype),
                              interpret=interpret)
     return rate[:slots], eta

@@ -28,31 +28,26 @@ def net_rerate(path, rem, link_bw, link_act, now, *, backend: str = "auto"
     """Re-rate transfer slots and scan for the next completion.
 
     See :func:`.ref.net_rerate_ref` for the argument contract. Returns a
-    host ``(rate, eta)`` pair regardless of backend.
+    host ``(rate, eta)`` pair regardless of backend. The kernel routes
+    scan relative to ``now`` and add it back on the host in float64, as
+    :func:`repro.kernels.event_engine.event_engine` does (exact in
+    float64, so the interpret route stays bit-identical to the oracle).
     """
-    if backend in ("auto", "pallas", "interpret"):
-        import jax
+    if backend != "numpy":
+        import jax  # deferred: the oracle route needs no jax
 
-        if backend == "pallas" or (backend == "auto"
-                                   and jax.default_backend() == "tpu"):
-            from .kernel import net_rerate_kernel
+        if backend == "auto":
+            backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
+    if backend in ("pallas", "interpret"):
+        from .kernel import net_rerate_kernel
+        interpret = backend == "interpret"
+        dtype = np.float64 if interpret else np.float32
+        with jax.enable_x64(interpret):
             rate, eta = net_rerate_kernel(
-                np.asarray(path, np.int32), np.asarray(rem, np.float32),
-                np.asarray(link_bw, np.float32),
-                np.asarray(link_act, np.float32), np.float32(now))
-            return np.asarray(rate, np.float64), float(eta)
-        if backend == "interpret":
-            from jax.experimental import enable_x64
-
-            from .kernel import net_rerate_kernel
-            with enable_x64():
-                rate, eta = net_rerate_kernel(
-                    np.asarray(path, np.int32), np.asarray(rem, np.float64),
-                    np.asarray(link_bw, np.float64),
-                    np.asarray(link_act, np.float64), np.float64(now),
-                    interpret=True)
-            return np.asarray(rate, np.float64), float(eta)
-        backend = "numpy"
+                np.asarray(path, np.int32), np.asarray(rem, dtype),
+                np.asarray(link_bw, dtype), np.asarray(link_act, dtype),
+                dtype(0.0), interpret=interpret)
+        return np.asarray(rate, np.float64), now + float(eta)
     if backend != "numpy":
         raise ValueError(f"unknown net_rerate backend {backend!r} "
                          "(want 'auto'|'pallas'|'interpret'|'numpy')")

@@ -23,7 +23,7 @@ Bit-identity: the holder max is order-independent and max/divide are
 exact IEEE ops; the file sum runs sequentially over ascending file index
 — the same order numpy reduces the major axis of a 2-D array — and a
 zero term leaves a nonnegative running sum unchanged, so under
-``jax.experimental.enable_x64`` interpret mode the kernel reproduces
+``jax.enable_x64`` interpret mode the kernel reproduces
 ``ref.st_cost_ref`` bit for bit (pinned by ``tests/test_kernels.py``).
 Compiled TPU execution is float32 (no f64 on TPU), so on TPU the route
 is approximate at the ~1e-7 relative level, like the other kernels.
@@ -42,14 +42,13 @@ _LANES = 128
 
 
 def _st_cost_kernel(bw_ref, fetch_ref, presence_t_ref, req_t_ref, sizes_ref,
-                    rel_ref, online_ref, out_ref):
-    bw = bw_ref[...]                  # (S_h, S)   [holder, dst]
-    fetch = fetch_ref[...]            # (S_h, F)   0/1 fetchable holders
-    presence_t = presence_t_ref[...]  # (F, S)     0/1 all holders
-    req_t = req_t_ref[...]            # (F, J)     0/1 requirement masks
-    n_f, n_s = presence_t.shape
-    n_j = req_t.shape[1]
-    dtype = bw.dtype
+                    rel_ref, online_ref, out_ref, t_fs_ref):
+    # bw (S_h, S) [holder, dst]; fetch (S_h, F) 0/1 fetchable holders;
+    # presence_t (F, S) 0/1 all holders; req_t (F, J) 0/1 requirement
+    # masks; t_fs (F, S) scratch for the per-(file, dst) staging times
+    n_f, n_s = presence_t_ref.shape
+    n_j = req_t_ref.shape[1]
+    dtype = bw_ref.dtype
 
     # Both loops run over the *padded* axes: padded holder rows hold no
     # files (zero contrib to the max) and padded files are required by no
@@ -58,31 +57,26 @@ def _st_cost_kernel(bw_ref, fetch_ref, presence_t_ref, req_t_ref, sizes_ref,
     # shape (multiples of 128) instead of retracing per batch-union size.
 
     # pass 1 — best fetchable bandwidth per (file, dst): running max over
-    # holder rows. Rows come off the lane axis and are stood up as columns
-    # (the same (n,) -> (n, 1) idiom value_score uses).
+    # holder rows. Each holder's fetch row is stood up as a column over
+    # files (the row -> column reshape value_score uses).
     def holder_body(h, best):
-        prow = jax.lax.dynamic_index_in_dim(fetch, h, 0,
-                                            keepdims=False)      # (F,)
-        brow = jax.lax.dynamic_index_in_dim(bw, h, 0,
-                                            keepdims=False)      # (S,)
-        contrib = jnp.where(prow[:, None] > 0.0, brow[None, :], 0.0)
+        pcol = fetch_ref[pl.ds(h, 1), :].reshape(-1, 1)          # (F, 1)
+        brow = bw_ref[pl.ds(h, 1), :]                            # (1, S)
+        contrib = jnp.where(pcol > 0.0, brow, 0.0)
         return jnp.maximum(best, contrib)
 
-    best = jax.lax.fori_loop(0, fetch.shape[0], holder_body,
+    best = jax.lax.fori_loop(0, fetch_ref.shape[0], holder_body,
                              jnp.zeros((n_f, n_s), dtype))
-    sizes_col = sizes_ref[0, :][:, None]                         # (F, 1)
-    t_fs = jnp.where(best > 0.0, sizes_col / best, jnp.inf)
+    sizes_col = sizes_ref[...].reshape(-1, 1)                    # (F, 1)
+    t_fs_ref[...] = jnp.where(best > 0.0, sizes_col / best, jnp.inf)
 
     # pass 2 — per-job staging time: sequential sum over ascending file
     # index of the missing files' transfer estimates.
     def file_body(f, acc):
-        req_row = jax.lax.dynamic_index_in_dim(req_t, f, 0,
-                                               keepdims=False)   # (J,)
-        pres_row = jax.lax.dynamic_index_in_dim(presence_t, f, 0,
-                                                keepdims=True)   # (1, S)
-        t_row = jax.lax.dynamic_index_in_dim(t_fs, f, 0,
-                                             keepdims=True)      # (1, S)
-        miss = (req_row[:, None] > 0.0) & (pres_row <= 0.0)      # (J, S)
+        req_col = req_t_ref[pl.ds(f, 1), :].reshape(-1, 1)       # (J, 1)
+        pres_row = presence_t_ref[pl.ds(f, 1), :]                # (1, S)
+        t_row = t_fs_ref[pl.ds(f, 1), :]                         # (1, S)
+        miss = (req_col > 0.0) & (pres_row <= 0.0)               # (J, S)
         return acc + jnp.where(miss, t_row, 0.0)
 
     t = jax.lax.fori_loop(0, n_f, file_body,
@@ -100,6 +94,7 @@ def _st_cost_call(bw, fetch, presence_t, req_t, sizes, rel, online, *,
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 7,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(out_shape, bw.dtype),
+        scratch_shapes=[pltpu.VMEM(presence_t.shape, bw.dtype)],
         interpret=interpret,
     )(bw, fetch, presence_t, req_t, sizes, rel, online)
 

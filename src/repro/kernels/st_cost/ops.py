@@ -30,36 +30,22 @@ def st_cost(bw, fetch_mask, presence, sizes, required, rel, online, *,
     See :func:`.ref.st_cost_ref` for the argument contract. Returns a
     host float64 array regardless of backend.
     """
-    if backend in ("auto", "pallas", "interpret"):
-        import jax
+    if backend != "numpy":
+        import jax  # deferred: the oracle route needs no jax
 
-        if backend == "pallas" or (backend == "auto"
-                                   and jax.default_backend() == "tpu"):
-            from .kernel import st_cost_kernel
+        if backend == "auto":
+            backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
+    if backend in ("pallas", "interpret"):
+        from .kernel import st_cost_kernel
+        interpret = backend == "interpret"
+        dtype = np.float64 if interpret else np.float32
+        with jax.enable_x64(interpret):
             out = st_cost_kernel(
-                np.asarray(bw, np.float32),
-                np.asarray(fetch_mask, np.float32),
-                np.asarray(presence, np.float32),
-                np.asarray(sizes, np.float32),
-                np.asarray(required, np.float32),
-                np.asarray(rel, np.float32),
-                np.asarray(online, np.float32))
-            return np.asarray(out, np.float64)
-        if backend == "interpret":
-            from jax.experimental import enable_x64
-
-            from .kernel import st_cost_kernel
-            with enable_x64():
-                out = st_cost_kernel(
-                    np.asarray(bw, np.float64),
-                    np.asarray(fetch_mask, np.float64),
-                    np.asarray(presence, np.float64),
-                    np.asarray(sizes, np.float64),
-                    np.asarray(required, np.float64),
-                    np.asarray(rel, np.float64),
-                    np.asarray(online, np.float64), interpret=True)
-            return np.asarray(out, np.float64)
-        backend = "numpy"
+                *(np.asarray(a, dtype) for a in (bw, fetch_mask, presence,
+                                                 sizes, required, rel,
+                                                 online)),
+                interpret=interpret)
+        return np.asarray(out, np.float64)
     if backend != "numpy":
         raise ValueError(f"unknown st_cost backend {backend!r} "
                          "(want 'auto'|'pallas'|'interpret'|'numpy')")

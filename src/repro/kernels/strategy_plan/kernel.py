@@ -23,7 +23,7 @@ garbage but sliced off.
 Bit-identity: the running maximum updates on strict ``>`` only, so ties
 keep the earliest site — exactly ``np.argmax``'s first occurrence — and
 where/divide/compare are exact IEEE ops, so under
-``jax.experimental.enable_x64`` interpret mode the kernel reproduces
+``jax.enable_x64`` interpret mode the kernel reproduces
 ``ref.strategy_plan_ref`` bit for bit (pinned by
 ``tests/test_kernels.py``). Compiled TPU execution is float32, the
 tolerance tier.
@@ -45,17 +45,14 @@ _SUBLANES = 8
 def _strategy_plan_kernel(bw_ref, fetch_ref, local_ref, free_ref, size_ref,
                           serve_ref, srcg_ref, srcl_ref, hasl_ref,
                           interg_ref, store_ref):
-    bw = bw_ref[...]                       # (S, P)
-    fetch = fetch_ref[...] > 0.0
-    local = local_ref[...] > 0.0
-    dtype = bw.dtype
-    n_pairs = bw.shape[1]
+    n_sites, n_pairs = bw_ref.shape        # (S, P)
+    dtype = bw_ref.dtype
 
     def site_body(h, carry):
         best_g, src_g, loc_g, best_l, src_l = carry    # each (1, P)
-        bw_row = jax.lax.dynamic_index_in_dim(bw, h, 0, keepdims=True)
-        f_row = jax.lax.dynamic_index_in_dim(fetch, h, 0, keepdims=True)
-        l_row = jax.lax.dynamic_index_in_dim(local, h, 0, keepdims=True)
+        bw_row = bw_ref[pl.ds(h, 1), :]
+        f_row = fetch_ref[pl.ds(h, 1), :] > 0.0
+        l_row = local_ref[pl.ds(h, 1), :] > 0.0
         eff = bw_row / (1.0 + serve_ref[0, h])
         key_g = jnp.where(f_row, eff, -1.0)
         key_l = jnp.where(f_row & l_row, eff, -1.0)
@@ -74,7 +71,7 @@ def _strategy_plan_kernel(bw_ref, fetch_ref, local_ref, free_ref, size_ref,
     neg = jnp.full((1, n_pairs), -2.0, dtype)
     zero = jnp.zeros((1, n_pairs), dtype)
     best_g, src_g, loc_g, best_l, src_l = jax.lax.fori_loop(
-        0, bw.shape[0], site_body, (neg, zero, zero, neg, zero))
+        0, n_sites, site_body, (neg, zero, zero, neg, zero))
     srcg_ref[...] = src_g
     srcl_ref[...] = src_l
     # a real local candidate scored >= 0 (bandwidth is nonnegative); the

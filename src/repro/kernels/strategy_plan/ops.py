@@ -35,34 +35,21 @@ def strategy_plan(bw, fetch, local, serve, free, size, *,
     store_ok)`` with decision dtypes (``intp`` site ids, ``bool`` flags)
     regardless of backend.
     """
-    if backend in ("auto", "pallas", "interpret"):
-        import jax
+    if backend != "numpy":
+        import jax  # deferred: the oracle route needs no jax
 
-        if backend == "pallas" or (backend == "auto"
-                                   and jax.default_backend() == "tpu"):
-            from .kernel import strategy_plan_kernel
+        if backend == "auto":
+            backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
+    if backend in ("pallas", "interpret"):
+        from .kernel import strategy_plan_kernel
+        interpret = backend == "interpret"
+        dtype = np.float64 if interpret else np.float32
+        with jax.enable_x64(interpret):
             out = strategy_plan_kernel(
-                np.asarray(bw, np.float32),
-                np.asarray(fetch, np.float32),
-                np.asarray(local, np.float32),
-                np.asarray(serve, np.float32),
-                np.asarray(free, np.float32),
-                np.asarray(size, np.float32))
-            return _decisions(*(np.asarray(o, np.float64) for o in out))
-        if backend == "interpret":
-            from jax.experimental import enable_x64
-
-            from .kernel import strategy_plan_kernel
-            with enable_x64():
-                out = strategy_plan_kernel(
-                    np.asarray(bw, np.float64),
-                    np.asarray(fetch, np.float64),
-                    np.asarray(local, np.float64),
-                    np.asarray(serve, np.float64),
-                    np.asarray(free, np.float64),
-                    np.asarray(size, np.float64), interpret=True)
-            return _decisions(*(np.asarray(o, np.float64) for o in out))
-        backend = "numpy"
+                *(np.asarray(a, dtype) for a in (bw, fetch, local, serve,
+                                                 free, size)),
+                interpret=interpret)
+        return _decisions(*(np.asarray(o, np.float64) for o in out))
     if backend != "numpy":
         raise ValueError(f"unknown strategy_plan backend {backend!r} "
                          "(want 'auto'|'pallas'|'interpret'|'numpy')")

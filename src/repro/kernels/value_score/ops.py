@@ -33,29 +33,21 @@ def value_score(demand, sizes, presence, bw, *, mode: str = "cost",
     if mode not in MODES:
         raise ValueError(f"unknown value_score mode {mode!r} "
                          f"(want one of {MODES})")
-    if backend in ("auto", "pallas", "interpret"):
-        import jax
+    if backend != "numpy":
+        import jax  # deferred: the oracle route needs no jax
 
-        if backend == "pallas" or (backend == "auto"
-                                   and jax.default_backend() == "tpu"):
-            from .kernel import value_score_kernel
+        if backend == "auto":
+            backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
+    if backend in ("pallas", "interpret"):
+        from .kernel import value_score_kernel
+        interpret = backend == "interpret"
+        dtype = np.float64 if interpret else np.float32
+        with jax.enable_x64(interpret):
             out = value_score_kernel(
-                np.asarray(demand, np.float32), np.asarray(sizes, np.float32),
-                np.asarray(presence, np.float32), np.asarray(bw, np.float32),
-                mode=mode)
-            return np.asarray(out, np.float64)
-        if backend == "interpret":
-            from jax.experimental import enable_x64
-
-            from .kernel import value_score_kernel
-            with enable_x64():
-                out = value_score_kernel(
-                    np.asarray(demand, np.float64),
-                    np.asarray(sizes, np.float64),
-                    np.asarray(presence, np.float64),
-                    np.asarray(bw, np.float64), mode=mode, interpret=True)
-            return np.asarray(out, np.float64)
-        backend = "numpy"
+                np.asarray(demand, dtype), np.asarray(sizes, dtype),
+                np.asarray(presence, dtype), np.asarray(bw, dtype),
+                mode=mode, interpret=interpret)
+        return np.asarray(out, np.float64)
     if backend != "numpy":
         raise ValueError(f"unknown value_score backend {backend!r} "
                          "(want 'auto'|'pallas'|'interpret'|'numpy')")

@@ -30,6 +30,7 @@ import os
 import time
 from typing import Iterable, Sequence
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (ExperimentResult, SCENARIOS, SWEEPS, ScenarioSpec,
                         SweepSpec, arrival_schedule, get_scenario, get_sweep,
                         injections, run_experiment, to_grid_config,
@@ -204,6 +205,7 @@ def main(argv: Sequence[str] | None = None) -> None:
                          "--obs trace, Perfetto trace + JSONL event log) "
                          "into DIR")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.list:
         for name, spec in sorted(SCENARIOS.items()):

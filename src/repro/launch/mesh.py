@@ -12,14 +12,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """Version-compat: ``jax.sharding.AxisType`` (and the ``axis_types``
-    kwarg of ``jax.make_mesh``) only exist on newer JAX. Older versions
-    default every axis to Auto anyway, so omitting the kwarg is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """Every axis Auto: the shardings are placed by the compiler."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

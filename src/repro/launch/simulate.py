@@ -16,6 +16,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (ChurnSpec, ECON_BACKENDS, OBS_MODES, SCENARIOS,
                         STRATEGIES, STRATEGY_MODES, SCHEDULERS, ScenarioSpec,
                         get_scenario)
@@ -63,6 +64,7 @@ def main() -> None:
     ap.add_argument("--failures", type=int, default=0,
                     help="number of random site failures to inject")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.scenario is not None:
         spec = get_scenario(args.scenario)

@@ -219,7 +219,8 @@ def test_engine_counters_surface_through_results():
     inc = run_experiment(cfg, n_jobs=80)                 # incremental numpy
     dev = run_experiment(cfg, n_jobs=80, net="device")   # batched device
     assert set(inc.net_stats) == {"rerate_calls", "rerate_slots",
-                                  "flush_passes", "flush_slots"}
+                                  "flush_passes", "flush_slots",
+                                  "flush_kernel", "flush_host"}
     # incremental engine: per-event union re-rates, never a fused flush
     assert inc.net_stats["rerate_slots"] > 0
     assert inc.net_stats["flush_passes"] == 0
@@ -227,6 +228,9 @@ def test_engine_counters_surface_through_results():
     assert dev.net_stats["rerate_slots"] == 0
     assert dev.net_stats["flush_passes"] > 0
     assert dev.net_stats["flush_slots"] > 0
+    # off the chip every pass that re-rated slots ran on the host
+    assert dev.net_stats["flush_kernel"] == 0
+    assert 0 < dev.net_stats["flush_host"] <= dev.net_stats["flush_passes"]
     # both engines saw the same event stream
     assert dev.net_stats["rerate_calls"] == inc.net_stats["rerate_calls"]
 

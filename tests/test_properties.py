@@ -159,7 +159,8 @@ def test_device_engine_matches_numpy(n_regions, spr, n_jobs, strategy, seed):
     a = run_experiment(cfg, strategy=strategy, n_jobs=n_jobs, net="numpy")
     b = run_experiment(cfg, strategy=strategy, n_jobs=n_jobs, net="device")
     assert b.completed_jobs == a.completed_jobs == n_jobs
-    assert b.total_inter_comms == a.total_inter_comms
+    # same jobs completed, so equal averages mean equal inter-region totals
+    assert b.avg_inter_comms == a.avg_inter_comms
     for metric in ("avg_job_time", "makespan", "total_wan_gb"):
         assert getattr(b, metric) == pytest.approx(getattr(a, metric),
                                                    rel=1e-9), metric
@@ -206,4 +207,4 @@ def test_device_engine_event_invariants(n_jobs, strategy, seed):
     sim._handle = spy
     res = sim.run()
     assert clock == sorted(clock)
-    assert res.completed_jobs == n_jobs
+    assert len(res.records) == n_jobs
