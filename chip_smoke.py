@@ -198,16 +198,6 @@ def _close(got, want, rtol: float = F32_RTOL) -> tuple[bool, float]:
     return err <= rtol, err
 
 
-def _timed(fn, *args, **kw):
-    """First call (compile + run) and a second call, host clock."""
-    t0 = time.perf_counter()
-    fn(*args, **kw)
-    first = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    return out, first, time.perf_counter() - t0
-
-
 def kernel_phase(w: Widths, backend: str,
                  counter: CompileCounter) -> list[str]:
     """Four kernels through their ops wrappers vs their numpy oracles."""
@@ -219,10 +209,9 @@ def kernel_phase(w: Widths, backend: str,
     rng = np.random.default_rng(SEED)
     failures = []
 
-    def report(name, shape, ok, err, first, second, before):
+    def report(name, shape, ok, err, before):
         _emit({"phase": "kernel", "kernel": name, "backend": backend,
                "shape": shape, "ok": ok, "max_rel_err": err,
-               "first_call_s": first, "second_call_s": second,
                **_since(counter, before)})
         if not ok:
             failures.append(f"kernel: {name} {shape} max rel err {err!r}")
@@ -238,14 +227,12 @@ def kernel_phase(w: Widths, backend: str,
     act = rng.integers(0, 400, w.links).astype(np.float64)
     now = 1.4e5
     before = counter.snapshot()
-    (rate, eta), first, second = _timed(net_rerate, path, rem, bw, act, now,
-                                        backend=backend)
+    rate, eta = net_rerate(path, rem, bw, act, now, backend=backend)
     rate_ref, eta_ref = net_rerate_ref(path, rem, bw, act, now)
     ok, err = _close(rate, rate_ref)
     eta_err = abs(eta - eta_ref) / (eta_ref - now)
     report("net_rerate", [w.slots, w.levels, w.links],
-           ok and eta_err <= F32_RTOL, max(err, eta_err), first, second,
-           before)
+           ok and eta_err <= F32_RTOL, max(err, eta_err), before)
 
     # st_cost: one 50-job burst over a 1 250-file batch union
     bw_ss = _f32(rng.random((w.sites, w.sites)) * 1.25e7 + 1e5)
@@ -262,9 +249,9 @@ def kernel_phase(w: Widths, backend: str,
     rel = _f32(rng.random(w.sites) * 50.0)
     args = (bw_ss, fetch, presence, sizes, required, rel, online)
     before = counter.snapshot()
-    out, first, second = _timed(st_cost, *args, backend=backend)
+    out = st_cost(*args, backend=backend)
     report("st_cost", [w.sites, w.pairs, w.jobs], *_close(out,
-           st_cost_ref(*args)), first, second, before)
+           st_cost_ref(*args)), before)
 
     # strategy_plan: bandwidths are multiples of 1 KiB below 2**24 and
     # 1 + serve a power of two, so every key is exact in float32 and the
@@ -279,11 +266,10 @@ def kernel_phase(w: Widths, backend: str,
     size = 2.0 ** 20 * rng.integers(1, 1024, w.pairs)
     args = (bw_sp, fetch_sp, local, serve, free, size)
     before = counter.snapshot()
-    out, first, second = _timed(strategy_plan, *args, backend=backend)
+    out = strategy_plan(*args, backend=backend)
     ref = strategy_plan(*args, backend="numpy")
     report("strategy_plan", [w.sites, w.pairs],
-           all(np.array_equal(a, b) for a, b in zip(out, ref)), 0.0,
-           first, second, before)
+           all(np.array_equal(a, b) for a, b in zip(out, ref)), 0.0, before)
 
     # value_score on the grid_500 and grid_500_evict catalogs
     bw_vs = _f32(rng.random((w.sites, w.sites)) * 1.25e7 + 1e5)
@@ -295,11 +281,9 @@ def kernel_phase(w: Widths, backend: str,
         presence[0] = True
         args = (demand, sizes, presence, bw_vs)
         before = counter.snapshot()
-        out, first, second = _timed(value_score, *args, mode=mode,
-                                    backend=backend)
+        out = value_score(*args, mode=mode, backend=backend)
         report(f"value_score[{mode}]", [w.sites, files],
-               *_close(out, value_score_ref(*args, mode=mode)), first,
-               second, before)
+               *_close(out, value_score_ref(*args, mode=mode)), before)
     return failures
 
 

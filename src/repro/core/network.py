@@ -156,6 +156,10 @@ class NetworkEngine:
         self.stats = {"rerate_calls": 0, "rerate_slots": 0,
                       "flush_passes": 0, "flush_slots": 0,
                       "flush_kernel": 0, "flush_host": 0}
+        # the run's repro.obs probe (GridSimulator hands it over; None
+        # when obs is off, and deep copies drop it): times the kernel
+        # route's flush in parts
+        self.probe = None
         self._pair_paths: Optional[np.ndarray] = None   # lazy (S, S, depth)
         # per-destination (link idx, validity) slices of the path tensor,
         # cached on first use: topology is static, only link shares move
@@ -482,18 +486,19 @@ class NetworkEngine:
             self.stats["flush_slots"] += self.n_active
             self.stats["flush_kernel" if self._use_kernel
                        else "flush_host"] += 1
-            out = self._flush_op(self.path, self.rem, self.rate, self.eta,
-                                 self.link_bw, self.link_act, now,
-                                 backend="pallas" if self._use_kernel
-                                 else "interpret")
-            rem_now, rate_new, eta_new, eta_min = out
-            self.rem[:] = rem_now
-            self.rate[:] = rate_new
-            self.eta[:] = eta_new
-            live = rate_new > 0.0
-            self.due[:] = np.where(
-                live, eta_new - _DONE_EPS / np.where(live, rate_new, 1.0),
-                np.inf)
+            args = (self.path, self.rem, self.rate, self.eta, self.link_bw,
+                    self.link_act, now)
+            backend = "pallas" if self._use_kernel else "interpret"
+            probe = self.probe
+            if probe is None:
+                rem_now, rate_new, eta_new, eta_min = self._flush_op(
+                    *args, backend=backend)
+                self._write_back(rem_now, rate_new, eta_new)
+            else:
+                rem_now, rate_new, eta_new, eta_min = self._flush_op(
+                    *args, backend=backend, probe=probe)
+                with probe.part("net.flush.apply"):
+                    self._write_back(rem_now, rate_new, eta_new)
             return eta_min if np.isfinite(eta_min) else None
         # CPU route: the same fused pass (float64 oracle) over the dirty
         # neighborhood, then the running-min over the eta array
@@ -551,3 +556,14 @@ class NetworkEngine:
                 np.inf)
         eta_min = float(self.eta.min())
         return eta_min if np.isfinite(eta_min) else None
+
+    def _write_back(self, rem_now, rate_new, eta_new) -> None:
+        """Kernel route: the flushed slot state over the whole array, and
+        each live slot's completion deadline."""
+        self.rem[:] = rem_now
+        self.rate[:] = rate_new
+        self.eta[:] = eta_new
+        live = rate_new > 0.0
+        self.due[:] = np.where(
+            live, eta_new - _DONE_EPS / np.where(live, rate_new, 1.0),
+            np.inf)

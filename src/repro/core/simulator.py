@@ -290,6 +290,7 @@ class GridSimulator:
             raise ValueError(f"unknown obs mode {obs!r} "
                              f"(want one of {OBS_MODES})")
         self._obs = make_probe(obs)
+        self.network.probe = self._obs
         self._obs_interval = (DEFAULT_OBS_INTERVAL_S if obs_interval is None
                               else obs_interval)
         self._obs_armed = False
@@ -960,6 +961,33 @@ class GridSimulator:
             self._obs_armed = True
             obs.sampler.sample(self)
             self._push(self.now + self._obs_interval, OBS, None)
+        if obs is None:
+            self._drain(until)
+        else:
+            with obs.running():     # the GC hook, removed however it ends
+                self._drain(until)
+        total_ic = sum(r.inter_comms for r in self.records)
+        telemetry = None
+        makespan = self.now
+        if obs is not None:
+            makespan = self._obs_real_now
+            obs.merge_counters("net", self.network.stats)
+            telemetry = obs.finalize(net_stats=self.network.stats)
+        return SimResult(
+            records=self.records,
+            total_inter_comms=total_ic,
+            total_wan_bytes=self.total_wan_bytes,
+            total_lan_bytes=self.total_lan_bytes,
+            makespan=makespan,
+            net_stats=dict(self.network.stats),
+            prefetches=self.access.prefetches,
+            prefetch_bytes=self.access.prefetch_bytes,
+            telemetry=telemetry,
+        )
+
+    def _drain(self, until: float) -> None:
+        """The event loop of :meth:`run`: handle events until the queue
+        empties or the next one lies past ``until``."""
         batched = self.network.batched
         while self._q:
             if self.sanitize:
@@ -981,24 +1009,6 @@ class GridSimulator:
                 break
             self.now = t
             self._handle(kind, payload)
-        total_ic = sum(r.inter_comms for r in self.records)
-        telemetry = None
-        makespan = self.now
-        if obs is not None:
-            makespan = self._obs_real_now
-            obs.merge_counters("net", self.network.stats)
-            telemetry = obs.finalize(net_stats=self.network.stats)
-        return SimResult(
-            records=self.records,
-            total_inter_comms=total_ic,
-            total_wan_bytes=self.total_wan_bytes,
-            total_lan_bytes=self.total_lan_bytes,
-            makespan=makespan,
-            net_stats=dict(self.network.stats),
-            prefetches=self.access.prefetches,
-            prefetch_bytes=self.access.prefetch_bytes,
-            telemetry=telemetry,
-        )
 
     def _handle(self, kind: int, payload: object) -> None:
         """Dispatch one popped event (``self.now`` already advanced),

@@ -92,29 +92,43 @@ def _flush_call(path, rem, rate, eta, link_bw, link_act, now, *,
     return rem_now[0], rate_new[0], eta_new[0], eta_min[0, 0]
 
 
+def kernel_inputs(path, rem, rate, eta, link_bw, link_act, now):
+    """The device inputs of :func:`_flush_call` and the slot count: the
+    host arrays transferred, the path transposed so slots ride the lanes,
+    and the slot axis padded to a lane multiple. Padded slots are all -1
+    path columns with zeroed state — they re-rate to 0 and an inf eta,
+    dropping out of the min. Dtypes follow ``rem``."""
+    path = jnp.asarray(path, jnp.int32)
+    rem = jnp.asarray(rem)
+    slots, levels = path.shape
+    pad_s = (-slots) % _LANES
+    pad_l = (-levels) % _SUBLANES
+    path_t = jnp.pad(path.T, ((0, pad_l), (0, pad_s)), constant_values=-1)
+    rem_p = jnp.pad(rem, (0, pad_s))
+    rate_p = jnp.pad(jnp.asarray(rate, rem.dtype), (0, pad_s))
+    eta_p = jnp.pad(jnp.asarray(eta, rem.dtype), (0, pad_s))
+    return (path_t, rem_p, rate_p, eta_p, jnp.asarray(link_bw, rem.dtype),
+            jnp.asarray(link_act, rem.dtype),
+            jnp.asarray(now, rem.dtype)), slots
+
+
+def kernel_launch(inputs, slots: int, *, interpret: bool = False):
+    """Dispatch the flush on :func:`kernel_inputs`' output; the results
+    stay on the device, cut back to ``slots``."""
+    if slots == 0:
+        z = jnp.zeros((0,), inputs[1].dtype)
+        return z, z, z, jnp.asarray(jnp.inf, inputs[1].dtype)
+    rem_now, rate_new, eta_new, eta_min = _flush_call(*inputs,
+                                                      interpret=interpret)
+    return rem_now[:slots], rate_new[:slots], eta_new[:slots], eta_min
+
+
 def event_engine_kernel(path, rem, rate, eta, link_bw, link_act, now, *,
                         interpret: bool = False):
     """Same contract as :func:`..ref.event_engine_ref`, computed by the
     Pallas kernel. ``path`` is ``(slots, max_links)`` (-1 padded); dtypes
     follow ``rem`` (float32 compiled on TPU, float64 under x64 interpret).
     """
-    path = jnp.asarray(path, jnp.int32)
-    rem = jnp.asarray(rem)
-    slots, levels = path.shape
-    if slots == 0:
-        z = jnp.zeros((0,), rem.dtype)
-        return z, z, z, jnp.asarray(jnp.inf, rem.dtype)
-    pad_s = (-slots) % _LANES
-    pad_l = (-levels) % _SUBLANES
-    # transpose so slots ride the lanes; padded slots are all -1 path
-    # columns with zeroed state — they re-rate to 0 and an inf eta,
-    # dropping out of the min
-    path_t = jnp.pad(path.T, ((0, pad_l), (0, pad_s)), constant_values=-1)
-    rem_p = jnp.pad(jnp.asarray(rem), (0, pad_s))
-    rate_p = jnp.pad(jnp.asarray(rate, rem.dtype), (0, pad_s))
-    eta_p = jnp.pad(jnp.asarray(eta, rem.dtype), (0, pad_s))
-    rem_now, rate_new, eta_new, eta_min = _flush_call(
-        path_t, rem_p, rate_p, eta_p, jnp.asarray(link_bw, rem.dtype),
-        jnp.asarray(link_act, rem.dtype), jnp.asarray(now, rem.dtype),
-        interpret=interpret)
-    return rem_now[:slots], rate_new[:slots], eta_new[:slots], eta_min
+    inputs, slots = kernel_inputs(path, rem, rate, eta, link_bw, link_act,
+                                  now)
+    return kernel_launch(inputs, slots, interpret=interpret)

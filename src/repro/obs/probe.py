@@ -12,6 +12,13 @@ A :class:`Probe` carries the three per-run telemetry surfaces:
   counters, plus ``probe.event(name, sim_t)`` which counts one DES
   event (``event.<KIND>``) and, in trace mode, records a sim-time
   instant in the Chrome trace.
+* **parts** — ``with probe.part("net.flush.stage"): ...`` timed stages
+  *within* the enclosing phase: each adds its elapsed nanoseconds to the
+  counter ``<name>_ns`` and carves nothing out of the phase's self time,
+  so the wall partition keeps its meaning.
+* **GC pauses** — for the life of a run (:meth:`Probe.running`) a
+  ``gc.callbacks`` hook counts Python's collections
+  (``host.gc.collections``) and their pause (``host.gc.pause_ns``).
 * **attachments** — an optional :class:`~repro.obs.series.GridSampler`
   (sim-time ring-buffer series) and
   :class:`~repro.obs.trace.TraceWriter` (Chrome trace export), owned
@@ -24,6 +31,12 @@ machine-checked by simlint rule SL014). Wall-clock reads are sanctioned
 here and only here among the sim-adjacent packages — simlint's SL005
 scope explicitly exempts ``repro/obs/``.
 
+Spans, parts and GC pauses also enter ``jax.profiler.TraceAnnotation``
+when jax is imported, so inside a ``jax.profiler.trace`` session they sit
+on the host thread beside JAX's dispatch spans, on the device's clock.
+The probe never imports jax itself: a profiler session needs jax, and
+the numpy routes never load it.
+
 Zero-overhead-when-disabled contract: the simulator stores ``None``
 instead of a probe when ``obs="off"``, so the engine hot paths pay one
 ``is None`` check and nothing else; this module is only imported, never
@@ -32,8 +45,11 @@ entered.
 
 from __future__ import annotations
 
+import contextlib
+import gc
+import sys
 import time
-from typing import TYPE_CHECKING, Optional
+from typing import TYPE_CHECKING, Iterator, Optional
 
 if TYPE_CHECKING:                      # imports for annotations only
     from .report import TelemetryReport
@@ -61,21 +77,33 @@ OBS_MODES = ("off", "report", "series", "trace")
 DEFAULT_OBS_INTERVAL_S = 300.0
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` when jax is already imported, else
+    None (no profiler session can be live without jax)."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.profiler.TraceAnnotation
+
+
 class _Span:
     """One active ``probe.span(name)`` context. Exclusive-time
     bookkeeping: ``child_s`` accumulates the *inclusive* seconds of
     directly nested spans, so on exit ``inclusive - child_s`` is this
     span's self time."""
 
-    __slots__ = ("probe", "name", "t0", "child_s")
+    __slots__ = ("probe", "name", "t0", "child_s", "ann")
 
     def __init__(self, probe: "Probe", name: str) -> None:
         self.probe = probe
         self.name = name
         self.t0 = 0.0
         self.child_s = 0.0
+        self.ann = None
 
     def __enter__(self) -> "_Span":
+        ann = self.probe._annotation
+        if ann is not None:
+            self.ann = ann(self.name)
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         self.probe._stack.append(self)
         return self
@@ -83,6 +111,8 @@ class _Span:
     def __exit__(self, *exc) -> None:
         p = self.probe
         incl = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         p._stack.pop()
         name = self.name
         p.phase_self_s[name] = (p.phase_self_s.get(name, 0.0)
@@ -93,6 +123,37 @@ class _Span:
             p._stack[-1].child_s += incl
         if p.trace is not None:
             p.trace.add_span(name, self.t0 - p._t0, incl)
+
+
+class _Part:
+    """One active ``probe.part(name)`` context: a timed stage inside the
+    enclosing span. Its nanoseconds go to the counter ``<name>_ns``; the
+    span stack and the phase tables are left alone."""
+
+    __slots__ = ("probe", "name", "t0", "ann")
+
+    def __init__(self, probe: "Probe", name: str) -> None:
+        self.probe = probe
+        self.name = name
+        self.t0 = 0
+        self.ann = None
+
+    def __enter__(self) -> "_Part":
+        ann = self.probe._annotation
+        if ann is not None:
+            self.ann = ann(self.name)
+            self.ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        p = self.probe
+        ns = time.perf_counter_ns() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        p.count(self.name + "_ns", ns)
+        if p.trace is not None:
+            p.trace.add_span(self.name, self.t0 * 1e-9 - p._t0, ns * 1e-9)
 
 
 class Probe:
@@ -117,6 +178,9 @@ class Probe:
         self.phase_total_s: dict[str, float] = {}
         self.phase_calls: dict[str, int] = {}
         self._stack: list[_Span] = []
+        self._annotation = _profiler_annotation()
+        self._gc_t0 = 0
+        self._gc_ann = None
         self._t0 = time.perf_counter()
         self.wall_s = 0.0
 
@@ -124,6 +188,11 @@ class Probe:
     def span(self, name: str) -> _Span:
         """Context manager timing one phase activation."""
         return _Span(self, name)
+
+    def part(self, name: str) -> _Part:
+        """Context manager timing one stage within the enclosing phase
+        into the counter ``<name>_ns`` (no self time carved out)."""
+        return _Part(self, name)
 
     def count(self, name: str, n: int = 1) -> None:
         """Bump a monotonic counter."""
@@ -145,6 +214,37 @@ class Probe:
             self.counters[key] = self.counters.get(key, 0) + int(values[k])
 
     # -- lifecycle ---------------------------------------------------------
+    @contextlib.contextmanager
+    def running(self) -> Iterator[None]:
+        """The life of one simulator run: re-resolves the profiler
+        annotation (jax may have been imported since the probe was made)
+        and hooks ``gc.callbacks`` until the run ends, raising or not.
+        The GC counters start at 0, so a run without a collection still
+        reports them."""
+        self._annotation = _profiler_annotation()
+        self.count("host.gc.collections", 0)
+        self.count("host.gc.pause_ns", 0)
+        gc.callbacks.append(self._on_gc)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = self._annotation
+            if ann is not None:
+                self._gc_ann = ann("host.gc")
+                self._gc_ann.__enter__()
+            self._gc_t0 = time.perf_counter_ns()
+            return
+        ns = time.perf_counter_ns() - self._gc_t0
+        if self._gc_ann is not None:
+            self._gc_ann.__exit__(None, None, None)
+            self._gc_ann = None
+        self.count("host.gc.collections")
+        self.count("host.gc.pause_ns", ns)
+
     def elapsed_us(self, name: str) -> float:
         """Total *inclusive* microseconds spent in phase ``name`` — the
         drop-in replacement for the bench harness's hand-rolled

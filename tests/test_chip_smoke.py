@@ -60,9 +60,9 @@ def test_chip_smoke_phases_pass_on_interpret_routes(monkeypatch, capsys):
     chip_smoke = _load_chip_smoke()
     real_op = event_engine_pkg.event_engine
 
-    def interpret_op(*args, backend):
+    def interpret_op(*args, backend, **kwargs):
         return real_op(*args, backend="interpret" if backend == "pallas"
-                       else backend)
+                       else backend, **kwargs)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(event_engine_pkg, "event_engine", interpret_op)

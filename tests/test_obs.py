@@ -280,3 +280,125 @@ def test_default_interval_exported():
     assert DEFAULT_OBS_INTERVAL_S == 300.0
     assert OBS_MODES == ("off", "report", "series", "trace")
     assert GridSampler().ring.capacity == 8192
+
+
+# -- parts, the GC hook and profiler annotations ----------------------------
+
+FLUSH_PARTS = ("net.flush.stage", "net.flush.launch", "net.flush.fetch",
+               "net.flush.apply")
+SMALL = GridConfig(n_regions=2, sites_per_region=3)
+
+
+def test_part_counts_ns_and_leaves_phases_alone():
+    """A part adds its nanoseconds to ``<name>_ns`` and carves nothing out
+    of the enclosing span: self time stays the inclusive time, and the
+    self times still partition wall."""
+    p = Probe("trace", trace=TraceWriter())
+    with p.span("net.flush"):
+        for _ in range(2):
+            with p.part("net.flush.stage"):
+                sum(range(2000))
+    ns = p.counters["net.flush.stage_ns"]
+    assert isinstance(ns, int) and 0 < ns <= p.phase_total_s["net.flush"] * 1e9
+    assert p.phase_calls == {"net.flush": 1}
+    assert p.phase_self_s["net.flush"] == p.phase_total_s["net.flush"]
+    report = p.finalize()
+    assert sum(report.phase_self_s.values()) <= report.wall_s
+    assert report.phase_breakdown()["flush_s"] == round(
+        p.phase_self_s["net.flush"], 6)
+    # trace mode: one nested Chrome event per part activation
+    spans = [e for e in p.trace.events if e.get("ph") == "X"]
+    outer = next(e for e in spans if e["name"] == "net.flush")
+    inner = [e for e in spans if e["name"] == "net.flush.stage"]
+    assert len(inner) == 2
+    for e in inner:
+        assert outer["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+
+
+@pytest.mark.parametrize("net", ["numpy", "pallas", "device",
+                                 "device-interpret"])
+def test_flush_parts_only_on_the_kernel_routes(net):
+    """The four flush parts are timed on the kernel flush routes alone
+    (here ``device-interpret``; ``device`` off the chip takes the host
+    oracle), and together stay within the ``net.flush`` phase."""
+    r = run_experiment(SMALL, n_jobs=10, net=net, obs="report")
+    tel = r.telemetry
+    parts = {n: tel.counters.get(n + "_ns") for n in FLUSH_PARTS}
+    if net != "device-interpret":
+        assert set(parts.values()) == {None}
+        return
+    assert all(v > 0 for v in parts.values()), parts
+    assert sum(parts.values()) <= tel.phase_total_s["net.flush"] * 1e9
+
+
+def test_obs_bit_identical_device_interpret():
+    base = _metrics(run_experiment(SMALL, n_jobs=10, net="device-interpret"))
+    got = _metrics(run_experiment(SMALL, n_jobs=10, net="device-interpret",
+                                  obs="report"))
+    assert got == base
+
+
+def test_gc_hook_counts_a_forced_collection():
+    import gc
+
+    p = Probe("report")
+    before = len(gc.callbacks)
+    with p.running():
+        assert len(gc.callbacks) == before + 1
+        gc.collect()
+    assert len(gc.callbacks) == before
+    assert p.counters["host.gc.collections"] >= 1
+    assert p.counters["host.gc.pause_ns"] > 0
+    # the hook is gone: a later collection is not counted
+    n = p.counters["host.gc.collections"]
+    gc.collect()
+    assert p.counters["host.gc.collections"] == n
+
+
+def test_gc_hook_removed_after_a_run_and_a_failed_one(monkeypatch):
+    import gc
+
+    before = len(gc.callbacks)
+    r = run_experiment(SMALL, n_jobs=10, obs="report")
+    assert len(gc.callbacks) == before
+    assert {"host.gc.collections", "host.gc.pause_ns"} <= set(
+        r.telemetry.counters)
+
+    def broken(self, kind, payload):
+        raise RuntimeError("handler fault")
+
+    monkeypatch.setattr(GridSimulator, "_handle_event", broken)
+    with pytest.raises(RuntimeError, match="handler fault"):
+        run_experiment(SMALL, n_jobs=10, obs="report")
+    assert len(gc.callbacks) == before
+
+
+def test_flush_parts_nest_in_net_flush_on_the_profiler_trace(tmp_path):
+    """Under ``jax.profiler.trace`` the program's spans and parts are
+    annotations on the host thread: each part of a flush lies inside its
+    ``net.flush``, in order (apply twice: the add-back of the flush
+    instant in the op wrapper, then the engine's write-back)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        run_experiment(SMALL, n_jobs=5, net="device-interpret", obs="report")
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    events = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+              for line in host.lines for ev in line.events]
+    flushes = [(s, e) for s, e, n in events if n == "net.flush"]
+    parts = [(s, e, n) for s, e, n in events if n in FLUSH_PARTS]
+    assert flushes and parts
+    nested = 0
+    for s0, e0 in flushes:
+        inside = sorted((s, e, n) for s, e, n in parts if s0 <= s and e <= e0)
+        if inside:
+            names = [n for _, _, n in inside]
+            assert names == [*FLUSH_PARTS, "net.flush.apply"], names
+            nested += 1
+    assert nested == len(parts) // 5 > 0
