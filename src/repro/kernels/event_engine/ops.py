@@ -45,12 +45,14 @@ def event_engine(path, rem, rate, eta, link_bw, link_act, now, *,
     rounding is monotone, so ``now + min(x) == min(now + x)`` — and the
     interpret and numpy routes stay bit-identical to the oracle.
 
-    With a ``probe`` the pass is timed in four parts: ``STAGE`` (host
-    arrays to the kernel's device inputs: casts, the shift, transfers,
-    pads, transpose), ``LAUNCH`` (the dispatch, outputs still on the
-    device), ``FETCH`` (the wait and the copy back) and ``APPLY`` (the
-    float64 add-back of ``now``). On the numpy route the oracle is the
-    launch and the fetch copies nothing.
+    With a ``probe`` the pass is timed in four parts: ``STAGE`` (the
+    kernel's inputs built on the host in numpy — casts, the shift, the
+    path's transpose, the pads — and moved by one ``jax.device_put``),
+    ``LAUNCH`` (the one program's dispatch, its output still on the
+    device), ``FETCH`` (the wait, the one copy back, and the cut to the
+    real slots widened to float64 in numpy) and ``APPLY`` (the float64
+    add-back of ``now``). On the numpy route the oracle is the launch and
+    the fetch copies nothing.
     """
     if backend != "numpy":
         import jax  # deferred: the oracle route needs no jax
@@ -62,31 +64,34 @@ def event_engine(path, rem, rate, eta, link_bw, link_act, now, *,
                          "(want 'auto'|'pallas'|'interpret'|'numpy')")
     args = (path, rem, rate, eta, link_bw, link_act, now)
     if probe is None:
-        return _add_now(_fetch(_launch(_stage(*args, backend), backend)),
-                        now)
+        return _add_now(_fetch(_launch(_stage(*args, backend), backend),
+                               backend), now)
     with probe.part(STAGE):
         staged = _stage(*args, backend)
     with probe.part(LAUNCH):
         out = _launch(staged, backend)
     with probe.part(FETCH):
-        out = _fetch(out)
+        out = _fetch(out, backend)
     with probe.part(APPLY):
         return _add_now(out, now)
 
 
 def _stage(path, rem, rate, eta, link_bw, link_act, now, backend):
-    dtype = np.float32 if backend == "pallas" else np.float64
-    args = (np.asarray(path, np.int32), np.asarray(rem, dtype),
-            np.asarray(rate, dtype),
-            (np.asarray(eta, np.float64) - now).astype(dtype),
-            np.asarray(link_bw, dtype), np.asarray(link_act, dtype))
     if backend == "numpy":
-        return args
+        return (np.asarray(path, np.int32), np.asarray(rem, np.float64),
+                np.asarray(rate, np.float64),
+                np.asarray(eta, np.float64) - now,
+                np.asarray(link_bw, np.float64),
+                np.asarray(link_act, np.float64))
     import jax
 
-    from .kernel import kernel_inputs
+    from .kernel import host_inputs
+    dtype = np.float32 if backend == "pallas" else np.float64
+    staged = host_inputs(np.asarray(path), rem, rate,
+                         np.asarray(eta, np.float64) - now, link_bw,
+                         link_act, dtype)
     with jax.enable_x64(backend == "interpret"):
-        return kernel_inputs(*args, dtype(0.0))
+        return jax.device_put(staged), len(path)
 
 
 def _launch(staged, backend):
@@ -94,16 +99,19 @@ def _launch(staged, backend):
         return event_engine_core(*staged, 0.0)
     import jax
 
-    from .kernel import kernel_launch
+    from .kernel import _flush_call
+    (path, floats), slots = staged
     interpret = backend == "interpret"
     with jax.enable_x64(interpret):
-        return kernel_launch(*staged, interpret=interpret)
+        return _flush_call(path, floats, interpret=interpret), slots
 
 
-def _fetch(out):
-    rem_now, rate_new, eta_new, eta_min = out
-    return (np.asarray(rem_now, np.float64), np.asarray(rate_new, np.float64),
-            np.asarray(eta_new, np.float64), float(eta_min))
+def _fetch(out, backend):
+    if backend == "numpy":
+        return out
+    from .kernel import host_outputs
+    packed, slots = out
+    return host_outputs(np.asarray(packed), slots)
 
 
 def _add_now(out, now):

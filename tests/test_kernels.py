@@ -181,6 +181,97 @@ def test_event_engine_all_released_returns_inf():
         assert np.isinf(eta_new).all() and np.isinf(eta_min)
 
 
+@pytest.mark.parametrize("slots", [0, 1, 37, 64, 128, 129])
+def test_event_engine_host_inputs_layout(slots):
+    """The flush's inputs built in numpy on the host are the kernel's
+    layout, as the traced wrapper builds it in jax: the path transposed
+    so slots ride the lanes, levels padded to 8 and slots to a multiple
+    of 128 (one lane group at least) with -1 ids, zeroed rem/rate/eta
+    pads, then the link arrays and ``now``. The interpret route over them
+    still matches the oracle."""
+    from repro.kernels.event_engine.kernel import host_inputs, kernel_inputs
+    links, levels = 23, 4
+    path, rem, rate, eta, bw, act = _event_engine_case(slots, slots, links,
+                                                       levels)
+    eta_rel = eta - 321.5
+    path_t, floats = host_inputs(path, rem, rate, eta_rel, bw, act,
+                                 np.float32)
+    s_pad = max(128, -(-slots // 128) * 128)
+    assert path_t.shape == (8, s_pad) and path_t.dtype == np.int32
+    assert np.array_equal(path_t[:levels, :slots], path.T)
+    assert (path_t[levels:] == -1).all() and (path_t[:, slots:] == -1).all()
+    assert floats.shape == (3 * s_pad + 2 * links + 1,)
+    assert floats.dtype == np.float32
+    rows = floats[:3 * s_pad].reshape(3, s_pad)
+    for row, want in zip(rows, (rem, rate, eta_rel)):
+        assert np.array_equal(row[:slots], want.astype(np.float32))
+        assert (row[slots:] == 0.0).all()
+    tail = floats[3 * s_pad:]
+    assert np.array_equal(tail, np.concatenate(
+        [bw, act, [0.0]]).astype(np.float32))
+    traced = kernel_inputs(path, rem.astype(np.float32), rate, eta_rel, bw,
+                           act, 0.0)
+    assert np.array_equal(np.asarray(traced[0]), path_t)
+    assert np.array_equal(np.asarray(traced[1]), floats)
+    ref = event_engine_ref(path, rem, rate, eta, bw, act, 321.5)
+    out = event_engine(path, rem, rate, eta, bw, act, 321.5,
+                       backend="interpret")
+    for got, want in zip(out, ref):
+        assert np.array_equal(got, want)
+
+
+def test_event_engine_flush_crosses_once_each_way(monkeypatch):
+    """A kernel-route flush makes one host-to-device call, dispatches one
+    program and makes one device-to-host copy, and runs no other jax op:
+    an eager op on host data needs a transfer that the guard refuses, and
+    the staged inputs and the program's output are held where the
+    wrapper can only hand them on (inputs) or copy them once (output)."""
+    import collections
+
+    from jax._src import dispatch
+
+    from repro.kernels.event_engine import kernel
+
+    case = _event_engine_case(1, 37, 23, 4)
+    want = event_engine_ref(*case, 321.5)
+    event_engine(*case, 321.5, backend="interpret")   # compile uncounted
+    counts = collections.Counter()
+
+    class Held:
+        def __init__(self, array):
+            self.array = array
+
+    class Fetched(Held):
+        def __array__(self, dtype=None, copy=None):
+            counts["to_host"] += 1
+            return np.asarray(self.array, dtype)
+
+    real_impl, real_put = dispatch._batched_device_put_impl, jax.device_put
+    real_call = kernel._flush_call
+
+    def impl(*args, **params):
+        counts["to_device"] += 1
+        return real_impl(*args, **params)
+
+    def put(x, *args, **kwargs):
+        counts["device_put"] += 1
+        return jax.tree.map(Held, real_put(x, *args, **kwargs))
+
+    def call(*held, **kwargs):
+        counts["programs"] += 1
+        return Fetched(real_call(*(h.array for h in held), **kwargs))
+
+    monkeypatch.setattr(dispatch, "_batched_device_put_impl", impl)
+    monkeypatch.setattr(jax, "device_put", put)
+    monkeypatch.setattr(kernel, "_flush_call", call)
+    with jax.transfer_guard_host_to_device("disallow"):
+        got = event_engine(*case, 321.5, backend="interpret")
+    assert counts == {"device_put": 1, "to_device": 1, "programs": 1,
+                      "to_host": 1}
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+
+
 def test_net_rerate_auto_backend_on_cpu_is_exact():
     """backend='auto' off-TPU routes to the float64 oracle — the fast path
     the net='pallas' engine uses per event on this container."""
