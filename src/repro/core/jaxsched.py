@@ -8,13 +8,18 @@ single fused XLA computation over:
   presence:  bool[n_sites, n_files]  — replica catalog as a bitmap
   sizes:     f32[n_files]            — file sizes
   required:  bool[n_files]           — the job's R_j as a mask
-  load:      f32[n_sites]            — queued work per site
-  capacity:  f32[n_sites]            — CE capacity per site
+  load_rank: f32[n_sites]            — relative-load rank per site
   online:    bool[n_sites]
 
 Tie-break is exact (no epsilon folding): stage 1 computes S_s and its max,
 stage 2 arg-minimizes relative load over the tied sites only. Both stages
-fuse into one XLA computation.
+fuse into one XLA computation. The relative load itself is taken on the
+host in float64, exactly as the sequential policies take it, and reaches
+the device as its dense rank (:meth:`JaxScheduler.site_state_np`):
+float32 queued work over float32 capacity would round both and divide
+on a chip whose division may be a few ulps off, splitting sites whose
+float64 loads tie. (:func:`select_sites_batch` still takes a capacity,
+which its callers pass as ones.)
 
 This module is also the bridge used by grid/placement.py to run dispatch
 on-device for batches of jobs (vmap over the job axis).
@@ -56,6 +61,7 @@ sequential path bit-for-bit.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -65,21 +71,25 @@ import numpy as np
 from .catalog import ReplicaCatalog
 from .topology import GridTopology
 
+#: the batch broker's stages, timed as parts of the simulator's
+#: ``broker.select_batch`` span when a probe is attached (``repro.obs``)
+STAGE, LAUNCH, FETCH = ("broker.batch.stage", "broker.batch.launch",
+                        "broker.batch.fetch")
+
 
 @functools.partial(jax.jit, static_argnames=())
-def select_site_vec(presence, sizes, required, load, capacity, online):
+def select_site_vec(presence, sizes, required, load_rank, online):
     """Paper §3.2 as one fused computation. Returns the chosen site index."""
     # S_s for every site: presence masked by the job's requirement
     s = (presence & required[None, :]) @ sizes              # [n_sites]
     s = jnp.where(online, s, -1.0)
     tie = s >= jnp.max(s)                                    # max-S_s sites
-    rel = load / capacity                                    # [n_sites]
-    rel = jnp.where(tie, rel, jnp.inf)
+    rel = jnp.where(tie, load_rank, jnp.inf)
     return jnp.argmin(rel)                                   # first min = min (rel, id)
 
 
 @jax.jit
-def select_sites_batch(presence, sizes, masks, load, capacity, online):
+def select_sites_batch(presence, sizes, masks, load_rank, capacity, online):
     """Batched :func:`select_site_vec`, reformulated as one GEMM.
 
     A straight ``vmap`` of the single-job scorer materializes a
@@ -89,12 +99,13 @@ def select_sites_batch(presence, sizes, masks, load, capacity, online):
     ``(jobs, files) x (files, sites)`` matmul instead. Same scores (file
     sizes are uniform per config, so the f32 sums are exact in any
     summation order), same tie-breaking as the vmapped form.
+    ``capacity`` divides ``load_rank``; the brokers pass ones.
     """
     w = masks.astype(sizes.dtype) * sizes                   # [jobs, files]
     s = w @ presence.T.astype(sizes.dtype)                  # [jobs, sites]
     s = jnp.where(online[None, :], s, -1.0)
     tie = s >= jnp.max(s, axis=1, keepdims=True)
-    rel = jnp.where(tie, (load / capacity)[None, :], jnp.inf)
+    rel = jnp.where(tie, (load_rank / capacity)[None, :], jnp.inf)
     return jnp.argmin(rel, axis=1)
 
 
@@ -102,7 +113,7 @@ class JaxScheduler:
     """Array-backed mirror of (catalog, topology) for on-device dispatch.
 
     Also the snapshot substrate for every jax broker: the host-side
-    presence bitmap, per-site load/capacity/online vectors and
+    presence bitmap, per-site load-rank/online vectors and
     required-file masks built here are shared with
     :class:`JaxShortestTransferBroker`.
 
@@ -123,6 +134,10 @@ class JaxScheduler:
         self.sizes = jnp.asarray(self._sizes_np, jnp.float32)
         self._n_catalog = len(catalog.files)
         self._presence: np.ndarray | None = None    # built on first use
+        # repro.obs probe, set by GridSimulator (None: telemetry off)
+        self.probe = None
+        # select_sites_batch's capacity, moved to the device once
+        self._ones = jnp.ones(topology.n_sites, jnp.float32)
         catalog.add_listener(self)
 
     # -- catalog change listeners (incremental presence maintenance) -------
@@ -193,12 +208,18 @@ class JaxScheduler:
             self._presence = presence
         return self._presence
 
-    def site_state_np(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """(load, capacity, online) per-site vectors."""
-        load = np.array([s.queued_work for s in self.topology.sites], np.float32)
-        cap = np.array([s.compute_capacity for s in self.topology.sites], np.float32)
+    def site_state_np(self) -> tuple[np.ndarray, np.ndarray]:
+        """(load_rank, online) per-site vectors.
+
+        ``load_rank`` is the dense rank of each site's float64 relative
+        load (``Site.relative_load``, the sequential policies' key):
+        equal loads share a rank, and small whole numbers keep their
+        order and their ties in float32 on any chip."""
+        rel = np.array([s.relative_load() for s in self.topology.sites],
+                       np.float64)
+        rank = np.unique(rel, return_inverse=True)[1].astype(np.float32)
         online = np.array([s.online for s in self.topology.sites], bool)
-        return load, cap, online
+        return rank, online
 
     def required_np(self, required_sets: list[list[str]]) -> np.ndarray:
         """bool[n_jobs, n_files] requirement masks (R_j rows)."""
@@ -218,40 +239,49 @@ class JaxScheduler:
             raise ValueError("no online sites to dispatch to")
 
     def snapshot(self):
-        load, cap, online = self.site_state_np()
+        load_rank, online = self.site_state_np()
         self._check_online(online)
         return (jnp.asarray(self.presence_np()), self.sizes,
-                jnp.asarray(load), jnp.asarray(cap), jnp.asarray(online))
+                jnp.asarray(load_rank), jnp.asarray(online))
 
     def required_mask(self, required: list[str]) -> jnp.ndarray:
         return jnp.asarray(self.required_np([required])[0])
 
     def select(self, required: list[str]) -> int:
         self.sync()
-        presence, sizes, load, cap, online = self.snapshot()
+        presence, sizes, load_rank, online = self.snapshot()
         return int(select_site_vec(presence, sizes, self.required_mask(required),
-                                   load, cap, online))
+                                   load_rank, online))
 
     def select_batch(self, required_sets: list[list[str]]) -> list[int]:
-        self.sync()
-        presence, sizes, load, cap, online = self.snapshot()
-        masks = jnp.asarray(self.required_np(required_sets))
-        # one host transfer for the whole batch (per-element int() would
-        # sync the device once per job)
-        return np.asarray(
-            select_sites_batch(presence, sizes, masks, load, cap, online)
-        ).tolist()
+        """Every job of a burst scored against one snapshot in one
+        device program; with a probe, timed in three parts: ``STAGE``
+        (the snapshot and the masks built and moved to the device),
+        ``LAUNCH`` (the program's dispatch) and ``FETCH`` (the wait and
+        the one copy of the picks back)."""
+        part = (contextlib.nullcontext if self.probe is None
+                else self.probe.part)
+        with part(STAGE):
+            self.sync()
+            presence, sizes, load_rank, online = self.snapshot()
+            masks = jnp.asarray(self.required_np(required_sets))
+        with part(LAUNCH):
+            picks = select_sites_batch(presence, sizes, masks, load_rank,
+                                       self._ones, online)
+        with part(FETCH):
+            # one host transfer for the whole batch (per-element int()
+            # would sync the device once per job)
+            return np.asarray(picks).tolist()
 
 
 @jax.jit
-def leastloaded_select(load, capacity, online):
-    """LeastLoaded as one fused computation: argmin of relative load over
-    online sites. ``jnp.argmin`` returns the first (lowest-id) minimum,
+def leastloaded_select(load_rank, online):
+    """LeastLoaded as one fused computation: argmin of the relative-load
+    rank over online sites. ``jnp.argmin`` returns the first (lowest-id) minimum,
     matching the sequential policy's ``(relative_load, site_id)`` key.
     Callers must reject all-offline snapshots host-side — an argmin over
     all-``inf`` would silently return site 0."""
-    rel = jnp.where(online, load / capacity, jnp.inf)
-    return jnp.argmin(rel)
+    return jnp.argmin(jnp.where(online, load_rank, jnp.inf))
 
 
 class JaxLeastLoadedBroker(JaxScheduler):
@@ -265,9 +295,9 @@ class JaxLeastLoadedBroker(JaxScheduler):
     """
 
     def select_batch(self, required_sets: list[list[str]]) -> list[int]:
-        load, cap, online = self.site_state_np()
+        load_rank, online = self.site_state_np()
         self._check_online(online)
-        site = int(leastloaded_select(jnp.asarray(load), jnp.asarray(cap),
+        site = int(leastloaded_select(jnp.asarray(load_rank),
                                       jnp.asarray(online)))
         return [site] * len(required_sets)
 
@@ -292,7 +322,7 @@ class JaxRandomBroker(JaxScheduler):
         self.rng = rng
 
     def select_batch(self, required_sets: list[list[str]]) -> list[int]:
-        _, _, online = self.site_state_np()
+        _, online = self.site_state_np()
         ids = np.flatnonzero(online)
         if ids.size == 0:
             raise IndexError("cannot choose from an empty online-site list")

@@ -43,14 +43,17 @@ Interchangeable backends (the ``net=`` engine flag):
     pinned to the numpy oracle by *tolerance* goldens
     (``tests/golden_tolerance.json``), not the bit-exact suite.
     ``"device-interpret"`` runs the same flush through the Pallas
-    interpreter under x64 (slow; bit-identical to the ``"device"`` CPU
-    route by the kernel's oracle-identity contract).
+    interpreter (slow; bit-identical to the ``"device"`` CPU route by the
+    kernel's oracle-identity contract). On TPU the chip picks each
+    slot's least fair share by its float64 rank and the host does the
+    flush's arithmetic in float64, so ``"device"`` is bit-identical to
+    its CPU route there too.
 
 On CPU (oracle and interpret routes) the numpy and pallas backends return
 identical results on identical histories; the golden suite pins this
 (``tests/test_golden_metrics.py``). The *compiled* TPU kernel computes in
 float32 (TPUs have no f64), so on TPU ``net="pallas"`` is an approximate
-backend — rates drift at the 1e-7 relative level — and the bit-identity
+backend — rates drift at the 1e-7 relative level — and its bit-identity
 contract applies to the CPU routes only.
 """
 

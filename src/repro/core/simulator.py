@@ -322,6 +322,7 @@ class GridSimulator:
                     "broker='jax' implements the 'dataaware', "
                     "'shortesttransfer', 'leastloaded' and 'random' "
                     f"policies; got scheduler {self.scheduler.name!r}")
+            self._jax_broker.probe = self._obs
         elif broker == "event":
             if batch_window > 0:
                 raise ValueError(
@@ -600,7 +601,7 @@ class GridSimulator:
         if obs is None:
             sites = self._jax_broker.select_batch([j.required for j in batch])
         else:
-            obs.count("broker.batches")
+            obs.count("broker.batch_calls")
             obs.count("broker.batch_jobs", len(batch))
             with obs.span("broker.select_batch"):
                 sites = self._jax_broker.select_batch(

@@ -7,12 +7,13 @@ wrapper returns host numpy values and picks the backend per call:
   * ``"auto"``   — the compiled Pallas kernel on TPU; the float64 numpy
     oracle on CPU (no per-instant jax dispatch overhead). This is what
     ``net="device"`` uses.
-  * ``"pallas"`` — force the compiled kernel. Compiled TPU execution is
-    float32 (no f64 on TPU): extra ~1e-7 relative drift on top of the
-    reconstruction drift the tolerance goldens already bound.
-  * ``"interpret"`` — the kernel under the Pallas interpreter with x64
-    enabled: slow, but bit-identical to the oracle; used by the kernel
-    tests and the ``net="device-interpret"`` engine flag.
+  * ``"pallas"`` — force the compiled kernel. The chip picks each
+    slot's least fair share by its float64 rank, and the host does the
+    arithmetic in float64, so the route is bit-identical to the oracle
+    on any chip (:mod:`.kernel`).
+  * ``"interpret"`` — the same program under the Pallas interpreter:
+    slow, bit-identical to the oracle; used by the kernel tests and the
+    ``net="device-interpret"`` engine flag.
   * ``"numpy"``  — the oracle directly.
 """
 
@@ -20,7 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from .ref import event_engine_core, event_engine_ref
+from .ref import event_engine_core, event_engine_ref, settle
 
 #: the flush's stages, timed as parts of the engine's ``net.flush`` phase
 #: when a probe is handed in (``repro.obs``)
@@ -38,21 +39,19 @@ def event_engine(path, rem, rate, eta, link_bw, link_act, now, *,
     backend.
 
     Every route sees times relative to the flush instant: ``eta - now``
-    is taken and ``now`` added back on the host in float64, so the
-    float32 chip resolves the gap to each completion instead of the
-    absolute clock (at a 1e6 s clock float32 steps by 0.06 s). In float64
-    the shift is exact — ``(eta - now) - 0`` is ``eta - now`` and
-    rounding is monotone, so ``now + min(x) == min(now + x)`` — and the
-    interpret and numpy routes stay bit-identical to the oracle.
+    is taken and ``now`` added back on the host in float64. The shift is
+    exact — ``(eta - now) - 0`` is ``eta - now`` and rounding is
+    monotone, so ``now + min(x) == min(now + x)`` — and every route
+    stays bit-identical to the oracle.
 
     With a ``probe`` the pass is timed in four parts: ``STAGE`` (the
-    kernel's inputs built on the host in numpy — casts, the shift, the
-    path's transpose, the pads — and moved by one ``jax.device_put``),
-    ``LAUNCH`` (the one program's dispatch, its output still on the
-    device), ``FETCH`` (the wait, the one copy back, and the cut to the
-    real slots widened to float64 in numpy) and ``APPLY`` (the float64
-    add-back of ``now``). On the numpy route the oracle is the launch and
-    the fetch copies nothing.
+    fair shares ranked in float64 and the path transposed and padded,
+    in numpy, and both moved by one ``jax.device_put``), ``LAUNCH`` (the
+    one program's dispatch, its output still on the device), ``FETCH``
+    (the wait, the one copy back, and the float64 rates and settle of
+    :func:`.ref.settle`) and ``APPLY`` (the float64 add-back of
+    ``now``). On the numpy route the oracle is the launch and the fetch
+    copies nothing.
     """
     if backend != "numpy":
         import jax  # deferred: the oracle route needs no jax
@@ -77,41 +76,35 @@ def event_engine(path, rem, rate, eta, link_bw, link_act, now, *,
 
 
 def _stage(path, rem, rate, eta, link_bw, link_act, now, backend):
+    rem, rate = np.asarray(rem, np.float64), np.asarray(rate, np.float64)
+    eta = np.asarray(eta, np.float64) - now
     if backend == "numpy":
-        return (np.asarray(path, np.int32), np.asarray(rem, np.float64),
-                np.asarray(rate, np.float64),
-                np.asarray(eta, np.float64) - now,
+        return (np.asarray(path, np.int32), rem, rate, eta,
                 np.asarray(link_bw, np.float64),
                 np.asarray(link_act, np.float64))
     import jax
 
     from .kernel import host_inputs
-    dtype = np.float32 if backend == "pallas" else np.float64
-    staged = host_inputs(np.asarray(path), rem, rate,
-                         np.asarray(eta, np.float64) - now, link_bw,
-                         link_act, dtype)
-    with jax.enable_x64(backend == "interpret"):
-        return jax.device_put(staged), len(path)
+    inputs, table = host_inputs(np.asarray(path), link_bw, link_act)
+    return jax.device_put(inputs), (len(path), table, rem, rate, eta)
 
 
 def _launch(staged, backend):
     if backend == "numpy":
         return event_engine_core(*staged, 0.0)
-    import jax
-
     from .kernel import _flush_call
-    (path, floats), slots = staged
-    interpret = backend == "interpret"
-    with jax.enable_x64(interpret):
-        return _flush_call(path, floats, interpret=interpret), slots
+    inputs, on_host = staged
+    return (_flush_call(*inputs, interpret=backend == "interpret"),
+            on_host)
 
 
 def _fetch(out, backend):
     if backend == "numpy":
         return out
-    from .kernel import host_outputs
-    packed, slots = out
-    return host_outputs(np.asarray(packed), slots)
+    from .kernel import host_rates
+    least, (slots, table, rem, rate, eta) = out
+    return settle(rem, rate, eta, host_rates(np.asarray(least), slots,
+                                             table), 0.0)
 
 
 def _add_now(out, now):

@@ -18,9 +18,10 @@ Step 1 is the deliberate fidelity break: the numpy oracle engine advances
 differently, so the device engine is *not* bit-identical to the numpy
 engine — it is pinned by the tolerance-golden contract
 (``tests/golden_tolerance.json``) instead. Within the device route itself
-every operation here is an exact IEEE op, so the Pallas kernel
-(``kernel.py``) under x64 interpret is bit-identical to this oracle —
-that contract the jaxpr auditor enforces.
+the chip only picks each slot's least share by rank (``kernel.py``) and
+:func:`settle` does the arithmetic on the host, so the route is
+bit-identical to this oracle on any chip; the traced kernel under x64
+is too — that contract the jaxpr auditor enforces.
 """
 
 from __future__ import annotations
@@ -82,6 +83,17 @@ def event_engine_core(path: np.ndarray, rem: np.ndarray, rate: np.ndarray,
     rate_new = shares[path[:, 0]]
     for d in range(1, path.shape[1]):
         np.minimum(rate_new, shares[path[:, d]], out=rate_new)
+    return settle(rem, rate, eta, rate_new, now)
+
+
+def settle(rem: np.ndarray, rate: np.ndarray, eta: np.ndarray,
+           rate_new: np.ndarray, now: float
+           ) -> tuple[np.ndarray, np.ndarray, np.ndarray, float]:
+    """The flush's float64 arithmetic once each slot's new rate is known
+    (``inf`` for all-padding rows): reconstruct ``rem``, recompute every
+    eta and reduce to the earliest. The device route calls it on the
+    rates its least-rank program picked (:mod:`.kernel`). Takes
+    ``rate_new`` over."""
     # all-padding rows reduced to the bare sentinel: dead, rate 0
     np.copyto(rate_new, 0.0, where=~np.isfinite(rate_new))
     # reconstruct remaining bytes from the cached (rate, eta) pair; slots
@@ -94,4 +106,4 @@ def event_engine_core(path: np.ndarray, rem: np.ndarray, rate: np.ndarray,
     live = rate_new > 0.0
     eta_new = np.where(live, now + rem_now / np.where(live, rate_new, 1.0),
                        np.inf)
-    return rem_now, rate_new, eta_new, float(eta_new.min())
+    return rem_now, rate_new, eta_new, float(eta_new.min(initial=np.inf))

@@ -186,38 +186,64 @@ def test_event_engine_host_inputs_layout(slots):
     """The flush's inputs built in numpy on the host are the kernel's
     layout, as the traced wrapper builds it in jax: the path transposed
     so slots ride the lanes, levels padded to 8 and slots to a multiple
-    of 128 (one lane group at least) with -1 ids, zeroed rem/rate/eta
-    pads, then the link arrays and ``now``. The interpret route over them
-    still matches the oracle."""
+    of 128 (one lane group at least) with -1 ids, then the int32 share
+    ranks behind the padding's sentinel, whose table maps each rank back
+    to its float64 share. The interpret route over them still matches
+    the oracle."""
     from repro.kernels.event_engine.kernel import host_inputs, kernel_inputs
     links, levels = 23, 4
     path, rem, rate, eta, bw, act = _event_engine_case(slots, slots, links,
                                                        levels)
-    eta_rel = eta - 321.5
-    path_t, floats = host_inputs(path, rem, rate, eta_rel, bw, act,
-                                 np.float32)
+    act[:6] = [0.0, 1.0, 2.0, 2.0, 4.0, 4.0]
+    bw[:6] = [1.25e8, 1.25e8, 2.5e8, 2.5e8, 5e8, 1.25e8]   # shares tie
+    (path_t, ranks), table = host_inputs(path, bw, act)
     s_pad = max(128, -(-slots // 128) * 128)
     assert path_t.shape == (8, s_pad) and path_t.dtype == np.int32
     assert np.array_equal(path_t[:levels, :slots], path.T)
     assert (path_t[levels:] == -1).all() and (path_t[:, slots:] == -1).all()
-    assert floats.shape == (3 * s_pad + 2 * links + 1,)
-    assert floats.dtype == np.float32
-    rows = floats[:3 * s_pad].reshape(3, s_pad)
-    for row, want in zip(rows, (rem, rate, eta_rel)):
-        assert np.array_equal(row[:slots], want.astype(np.float32))
-        assert (row[slots:] == 0.0).all()
-    tail = floats[3 * s_pad:]
-    assert np.array_equal(tail, np.concatenate(
-        [bw, act, [0.0]]).astype(np.float32))
-    traced = kernel_inputs(path, rem.astype(np.float32), rate, eta_rel, bw,
-                           act, 0.0)
-    assert np.array_equal(np.asarray(traced[0]), path_t)
-    assert np.array_equal(np.asarray(traced[1]), floats)
+    share = bw / np.maximum(1.0, act)
+    assert ranks.dtype == np.int32 and ranks[0] == links
+    assert np.array_equal(table[ranks[1:]], share)
+    assert ranks[1] == ranks[2] == ranks[3] == ranks[4] != ranks[6]
+    assert np.array_equal(np.argsort(ranks[1:], kind="stable"),
+                          np.argsort(share, kind="stable"))
+    assert table[links] == np.inf
+    (t_path, t_ranks), t_table = kernel_inputs(path, bw, act, jnp.float32)
+    assert np.array_equal(np.asarray(t_path), path_t)
+    assert np.array_equal(np.asarray(t_ranks),
+                          host_inputs(path, bw.astype(np.float32),
+                                      act)[0][1])
     ref = event_engine_ref(path, rem, rate, eta, bw, act, 321.5)
     out = event_engine(path, rem, rate, eta, bw, act, 321.5,
                        backend="interpret")
     for got, want in zip(out, ref):
         assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("users,left_s", [(9, 3.7), (9, 12.3), (11, 3.7)])
+def test_event_engine_kernel_route_keeps_float64_ties(users, left_s):
+    """Two transfers that float64 ends at one instant still end at one
+    instant on the kernel route, where a float32 flush split them: one
+    alone on a 1000 Mbps link, one sharing another with ``users - 1``
+    more, both ``left_s`` seconds from the end at their unchanged rates.
+    The route's outputs equal the oracle's bit for bit in a process
+    without x64; the float32 flush, computed here as a mutation, puts
+    the two ends an ulp apart."""
+    path = np.array([[0, -1], [1, -1]])
+    bw, act = np.full(2, 1.25e8), np.array([1.0, users])
+    rate = bw / act
+    rem = np.zeros(2)
+    eta = np.full(2, left_s)
+    want = event_engine_ref(path, rem, rate, eta, bw, act, 0.0)
+    assert want[2][0] == want[2][1]
+    got = event_engine(path, rem, rate, eta, bw, act, 0.0,
+                       backend="interpret")
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    f32 = np.float32
+    rate32 = bw.astype(f32) / act.astype(f32)
+    eta32 = rate.astype(f32) * eta.astype(f32) / rate32
+    assert eta32[0] != eta32[1]
 
 
 def test_event_engine_flush_crosses_once_each_way(monkeypatch):

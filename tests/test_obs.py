@@ -402,3 +402,61 @@ def test_flush_parts_nest_in_net_flush_on_the_profiler_trace(tmp_path):
             assert names == [*FLUSH_PARTS, "net.flush.apply"], names
             nested += 1
     assert nested == len(parts) // 5 > 0
+
+
+BATCH_PARTS = ("broker.batch.stage", "broker.batch.launch",
+               "broker.batch.fetch")
+
+
+def test_batch_parts_nest_in_select_batch_on_the_profiler_trace(tmp_path):
+    """The bulk broker's three parts lie inside each ``broker.select_batch``
+    on a ``jax.profiler`` trace, in order; the counters count its calls
+    and the jobs they placed."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        r = run_experiment(SMALL, n_jobs=12, broker="jax", arrival_burst=4,
+                           obs="report")
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    events = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+              for line in host.lines for ev in line.events]
+    calls = [(s, e) for s, e, n in events if n == "broker.select_batch"]
+    parts = [(s, e, n) for s, e, n in events if n in BATCH_PARTS]
+    assert len(calls) == 3 and len(parts) == 9
+    for s0, e0 in calls:
+        inside = sorted((s, e, n) for s, e, n in parts if s0 <= s and e <= e0)
+        assert [n for _, _, n in inside] == list(BATCH_PARTS)
+    tel = r.telemetry
+    assert tel.counters["broker.batch_calls"] == 3
+    assert tel.counters["broker.batch_jobs"] == 12
+    assert tel.phase_calls["broker.select_batch"] == 3
+    ns = sum(tel.counters[n + "_ns"] for n in BATCH_PARTS)
+    assert 0 < ns <= tel.phase_total_s["broker.select_batch"] * 1e9
+
+
+def test_batch_broker_picks_bit_identical_with_obs_off():
+    """The parts time the broker and change none of its picks."""
+    from repro.core import build_catalog, build_topology, generate_jobs
+    from repro.core.jaxsched import JaxScheduler
+
+    picks = []
+    for probe in (None, Probe("report")):
+        topo = build_topology(SMALL)
+        cat = build_catalog(SMALL, topo)
+        for i, s in enumerate(topo.sites):
+            s.queued_work = (i % 3) * 60e9
+        broker = JaxScheduler(cat, topo)
+        broker.probe = probe
+        picks.append(broker.select_batch(
+            [j.required for j in generate_jobs(SMALL, 20)]))
+    assert picks[0] == picks[1]
+    assert probe.counters["broker.batch.launch_ns"] > 0
+    off = run_experiment(SMALL, n_jobs=12, broker="jax", arrival_burst=4)
+    on = run_experiment(SMALL, n_jobs=12, broker="jax", arrival_burst=4,
+                        obs="report")
+    assert _metrics(on) == _metrics(off)
