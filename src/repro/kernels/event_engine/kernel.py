@@ -32,9 +32,10 @@ small level axis the sublanes. One program sees the whole batch.
 A flush crosses between host and device once each way. The engine's
 route (:mod:`.ops`) builds both inputs of :func:`_flush_call` in numpy
 (:func:`host_inputs`: the transposed, padded int32 path and the int32
-ranks), moves them with one ``jax.device_put``, runs the one program —
-gather, kernel — and copies its ``(1, slots)`` int32 output back, which
-:func:`host_rates` turns into float64 rates of the real slots.
+ranks) and hands them to the one program — gather, kernel — whose
+dispatch moves them to the device (no ``jax.device_put``); it copies
+the ``(1, slots)`` int32 output back, which :func:`host_rates` turns
+into float64 rates of the real slots.
 :func:`event_engine_kernel` is the whole flush for a traced caller (the
 jaxpr audit, the TPU compile test): the same program, with the shares,
 ranks and settle computed in jax in ``rem``'s dtype.
@@ -104,9 +105,10 @@ def share_ranks(link_bw, link_act):
 
 def host_inputs(path, link_bw, link_act):
     """The two inputs of :func:`_flush_call`, built in numpy on the host
-    so that one transfer moves them: the ``(levels, slots)`` int32 path
-    (transposed so slots ride the lanes, ``-1`` padded) and the ranks of
-    :func:`share_ranks`; and the table that :func:`host_rates` reads.
+    and handed to its dispatch as they are: the ``(levels, slots)``
+    int32 path (transposed so slots ride the lanes, ``-1`` padded) and
+    the ranks of :func:`share_ranks`; and the table that
+    :func:`host_rates` reads.
     Padded slots reduce to the sentinel and re-rate to 0."""
     slots, levels = path.shape
     s_pad, l_pad = _padded(slots, levels)

@@ -46,12 +46,18 @@ def event_engine(path, rem, rate, eta, link_bw, link_act, now, *,
 
     With a ``probe`` the pass is timed in four parts: ``STAGE`` (the
     fair shares ranked in float64 and the path transposed and padded,
-    in numpy, and both moved by one ``jax.device_put``), ``LAUNCH`` (the
-    one program's dispatch, its output still on the device), ``FETCH``
-    (the wait, the one copy back, and the float64 rates and settle of
-    :func:`.ref.settle`) and ``APPLY`` (the float64 add-back of
-    ``now``). On the numpy route the oracle is the launch and the fetch
-    copies nothing.
+    in numpy only), ``LAUNCH`` (the one program's dispatch, handed the
+    numpy operands, so it carries their one transfer to the device; its
+    output stays there), ``FETCH`` (the wait, the one copy back, and
+    the float64 rates and settle of :func:`.ref.settle`) and ``APPLY``
+    (the float64 add-back of ``now``). On the numpy route the oracle is
+    the launch and the fetch copies nothing.
+
+    The kernel routes make no ``jax.device_put`` and no eager jax op:
+    the jitted program's own dispatch moves its numpy operands, which
+    skips JAX's Python transfer layer. Every call passes numpy
+    operands, so the program has one jit cache entry per shape and the
+    warm-up that runs this op covers the window.
     """
     if backend != "numpy":
         import jax  # deferred: the oracle route needs no jax
@@ -82,11 +88,9 @@ def _stage(path, rem, rate, eta, link_bw, link_act, now, backend):
         return (np.asarray(path, np.int32), rem, rate, eta,
                 np.asarray(link_bw, np.float64),
                 np.asarray(link_act, np.float64))
-    import jax
-
     from .kernel import host_inputs
     inputs, table = host_inputs(np.asarray(path), link_bw, link_act)
-    return jax.device_put(inputs), (len(path), table, rem, rate, eta)
+    return inputs, (len(path), table, rem, rate, eta)
 
 
 def _launch(staged, backend):

@@ -247,11 +247,12 @@ def test_event_engine_kernel_route_keeps_float64_ties(users, left_s):
 
 
 def test_event_engine_flush_crosses_once_each_way(monkeypatch):
-    """A kernel-route flush makes one host-to-device call, dispatches one
-    program and makes one device-to-host copy, and runs no other jax op:
-    an eager op on host data needs a transfer that the guard refuses, and
-    the staged inputs and the program's output are held where the
-    wrapper can only hand them on (inputs) or copy them once (output)."""
+    """A kernel-route flush makes no ``jax.device_put``: it dispatches one
+    program whose operands are host numpy arrays, and that dispatch is
+    its only host-to-device transfer (the guard allows transfers inside
+    the program's call alone, so an eager op on host data anywhere else
+    is refused). The program's output is held where the wrapper can only
+    copy it once, and the result is bit-identical to the oracle."""
     import collections
 
     from jax._src import dispatch
@@ -263,11 +264,10 @@ def test_event_engine_flush_crosses_once_each_way(monkeypatch):
     event_engine(*case, 321.5, backend="interpret")   # compile uncounted
     counts = collections.Counter()
 
-    class Held:
+    class Fetched:
         def __init__(self, array):
             self.array = array
 
-    class Fetched(Held):
         def __array__(self, dtype=None, copy=None):
             counts["to_host"] += 1
             return np.asarray(self.array, dtype)
@@ -279,23 +279,48 @@ def test_event_engine_flush_crosses_once_each_way(monkeypatch):
         counts["to_device"] += 1
         return real_impl(*args, **params)
 
-    def put(x, *args, **kwargs):
+    def put(*args, **kwargs):
         counts["device_put"] += 1
-        return jax.tree.map(Held, real_put(x, *args, **kwargs))
+        return real_put(*args, **kwargs)
 
-    def call(*held, **kwargs):
+    def call(*operands, **kwargs):
         counts["programs"] += 1
-        return Fetched(real_call(*(h.array for h in held), **kwargs))
+        assert all(type(x) is np.ndarray for x in operands)
+        with jax.transfer_guard_host_to_device("allow"):
+            return Fetched(real_call(*operands, **kwargs))
 
     monkeypatch.setattr(dispatch, "_batched_device_put_impl", impl)
     monkeypatch.setattr(jax, "device_put", put)
     monkeypatch.setattr(kernel, "_flush_call", call)
     with jax.transfer_guard_host_to_device("disallow"):
         got = event_engine(*case, 321.5, backend="interpret")
-    assert counts == {"device_put": 1, "to_device": 1, "programs": 1,
-                      "to_host": 1}
+    assert counts == {"programs": 1, "to_host": 1}
     for g, w in zip(got, want):
         assert np.array_equal(g, w)
+
+
+@pytest.mark.parametrize("slots", [64, 200])
+def test_event_engine_warm_flush_adds_no_compile(slots):
+    """One flush at a slot capacity warms every later flush there: the
+    route always hands the program numpy operands, so flushes of other
+    slot states at that capacity add no entry to its jit cache (a call
+    on device operands would add a second one). The least ranks it
+    gives equal those of the same operands put on the device first."""
+    from repro.kernels.event_engine.kernel import _flush_call, host_inputs
+    cases = [_event_engine_case(seed, slots, 57, 2) for seed in range(4)]
+    event_engine(*cases[0], 321.5, backend="interpret")
+    warm = _flush_call._cache_size()
+    for case in cases[1:]:
+        got = event_engine(*case, 321.5, backend="interpret")
+        for g, w in zip(got, event_engine_ref(*case, 321.5)):
+            assert np.array_equal(g, w)
+    assert _flush_call._cache_size() == warm
+    path, _, _, _, bw, act = cases[-1]
+    inputs, _ = host_inputs(path, bw, act)
+    on_host = np.asarray(_flush_call(*inputs, interpret=True))
+    put = np.asarray(_flush_call(*jax.device_put(inputs), interpret=True))
+    assert on_host.dtype == put.dtype == np.int32
+    assert np.array_equal(on_host, put)
 
 
 def test_net_rerate_auto_backend_on_cpu_is_exact():
