@@ -22,8 +22,8 @@ tolerance run, a scale sweep through the batch-dispatch broker — 2k/5k/
 10k jobs on the paper grid, the 500-site rungs (incl. the saturated
 numpy-vs-device engine pair) and the 5000-site/1M-job batched-engine
 rung (writes ``results/BENCH_scale.json``), a network-engine sweep
-quantifying the per-link path-contention fidelity change and the
-vectorized re-rate backend (writes ``results/BENCH_net.json``), kernel
+quantifying the per-link path-contention fidelity change (writes
+``results/BENCH_net.json``), kernel
 µbenches (interpret mode on CPU).
 
 Run ``python benchmarks/run.py --help`` for the bench list; name benches
@@ -397,10 +397,9 @@ def strategy_sweep(n_jobs: int = 10000) -> None:
 
 
 def net_sweep(n_jobs: int = 10000) -> None:
-    """Network-engine sweep: (a) fidelity — deep-tree scenarios under the
-    legacy topmost-uplink model vs the per-link path model; (b) performance
-    — the numpy incremental backend vs the pallas/vectorized full re-rate
-    at the 10k-job scale point. Writes ``results/BENCH_net.json``."""
+    """Network-engine fidelity sweep: deep-tree scenarios under the legacy
+    topmost-uplink model vs the per-link path model (the incremental
+    numpy engine). Writes ``results/BENCH_net.json``."""
     from repro.core import SCENARIOS
     from repro.launch.experiments import run_spec
     p = _probe()
@@ -421,35 +420,17 @@ def net_sweep(n_jobs: int = 10000) -> None:
                 "makespan_s": r.makespan,
                 "completed_jobs": r.completed_jobs,
             })
-    perf = []
-    bulk = SCENARIOS["bulk_diana"]
-    for net in ("numpy", "pallas"):
-        spec = dataclasses.replace(bulk, net=net)
-        cell = f"perf:{net}"
-        with p.span(cell):
-            r = run_spec(spec, n_jobs=n_jobs)
-        perf.append({
-            "scenario": "bulk_diana", "net": net, "n_jobs": n_jobs,
-            "wall_s": round(p.elapsed_us(cell) / US_PER_S, 3),
-            "avg_job_time_s": r.avg_job_time,
-            "completed_jobs": r.completed_jobs,
-        })
     os.makedirs(RESULTS_DIR, exist_ok=True)
     with open(os.path.join(RESULTS_DIR, "BENCH_net.json"), "w") as f:
-        json.dump({"n_jobs": n_jobs, "fidelity": fidelity, "perf": perf},
-                  f, indent=1)
-    us = sum(p.phase_total_s.values()) * US_PER_S / (len(fidelity) + len(perf))
+        json.dump({"n_jobs": n_jobs, "fidelity": fidelity}, f, indent=1)
+    us = sum(p.phase_total_s.values()) * US_PER_S / len(fidelity)
     by = {(r["scenario"], r["net"]): r for r in fidelity}
     d5 = (by[("deep_5tier", "numpy")]["avg_job_time_s"]
           / by[("deep_5tier", "topmost")]["avg_job_time_s"] - 1.0)
     dc = (by[("deep_contended", "numpy")]["avg_job_time_s"]
           / by[("deep_contended", "topmost")]["avg_job_time_s"] - 1.0)
-    speedup = perf[0]["wall_s"] / max(perf[1]["wall_s"], 1e-9)
     _row("net_sweep", us,
-         f"deep5_fidelity={100 * d5:+.1f}%;contended_fidelity={100 * dc:+.1f}%;"
-         f"pallas_vs_numpy_wall={speedup:.2f}x;"
-         f"numpy_10k_wall={perf[0]['wall_s']:.1f}s;"
-         f"pallas_10k_wall={perf[1]['wall_s']:.1f}s")
+         f"deep5_fidelity={100 * d5:+.1f}%;contended_fidelity={100 * dc:+.1f}%")
 
 
 def kernel_flash_attention() -> None:
@@ -521,8 +502,8 @@ BENCHES = {
                        "cache_starved + hotset_drift -> "
                        "BENCH_strategies.json"),
     "net_sweep": (net_sweep,
-                  "network-engine sweep: topmost-vs-path fidelity + "
-                  "numpy-vs-pallas re-rate perf -> BENCH_net.json"),
+                  "network-engine sweep: topmost-vs-path fidelity "
+                  "-> BENCH_net.json"),
     "kernel_flash": (kernel_flash_attention, "flash-attention µbench (CPU ref)"),
     "kernel_scan": (kernel_selective_scan, "selective-scan µbench (CPU ref)"),
 }

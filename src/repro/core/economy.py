@@ -28,8 +28,7 @@ Both models pool demand across the region (a site profits from staging a
 file its region-mates keep fetching — the replica serves them over the
 LAN), and both are scored by the vectorized
 :mod:`repro.kernels.value_score` backend selected with the ``econ=``
-engine flag (``numpy`` | ``pallas`` | ``pallas-interpret``), mirroring
-``net=``.
+engine flag (``numpy`` | ``pallas`` | ``pallas-interpret``).
 """
 
 from __future__ import annotations
@@ -44,7 +43,7 @@ from .network import NetworkEngine
 from .replica import FetchPlan, StorageState
 from .topology import GridTopology
 
-#: Values the ``econ=`` engine flag accepts, mirroring ``net=``:
+#: Values the ``econ=`` engine flag accepts:
 #: ``numpy`` scores with the float64 oracle, ``pallas`` routes through
 #: the kernel op (compiled on TPU, the identical oracle on CPU),
 #: ``pallas-interpret`` runs the kernel under the Pallas interpreter

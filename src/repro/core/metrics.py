@@ -71,9 +71,11 @@ def run_experiment(
 
     ``net`` picks the network-engine backend (see
     :data:`repro.core.simulator.NETS`): ``"numpy"`` incremental re-rating,
-    ``"pallas"`` the vectorized/kernel full re-rate, ``"topmost"`` the
-    legacy single-uplink accounting (fidelity baseline). Identical results
-    on two-level grids under all of them.
+    ``"device"`` / ``"device-interpret"`` the batched event-instant flush
+    (:class:`repro.core.network.NetworkEngine`), ``"topmost"`` the legacy
+    single-uplink accounting (fidelity baseline). ``"numpy"`` and
+    ``"topmost"`` give identical results on two-level grids; the batched
+    routes stay within the tolerance goldens of ``"numpy"``.
 
     ``strategy_mode`` picks the planning engine of the replication
     strategy: ``"sequential"`` (one ``plan_fetch`` per missing file — the

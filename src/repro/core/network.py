@@ -10,24 +10,14 @@ so mid-tier uplinks congest under through-traffic on deep trees; on
 two-level grids the row is exactly the legacy {source NIC, region uplink}
 pair and results are bit-identical to the pre-refactor engine.
 
-Interchangeable backends (the ``net=`` engine flag):
+Three routes (the ``net=`` engine flag):
 
 ``"numpy"`` (default)
     Incremental re-rating: only slots sharing a link whose membership
     changed are re-rated (rates are pure functions of link occupancy, so
     this equals a full recompute — bit-identically). Small groups take a
-    scalar fast path; larger ones a vectorized gather-min.
-
-``"pallas"``
-    The ``repro.kernels.net_rerate`` formulation: a per-link share vector
-    per event, then one gather-min per changed-link batch — the compiled
-    Pallas kernel on TPU, the identical inline numpy expression on CPU —
-    so 100k-transfer batches re-rate as one fused pass instead of a
-    python loop (and beat the incremental backend at the 10k-job scale
-    point). ``"pallas-interpret"`` instead runs the *full* slot array
-    plus the next-completion scan through the kernel under the Pallas
-    interpreter every event (slow; extends the bit-identity contract to
-    the kernel itself).
+    scalar fast path; larger ones a vectorized gather-min. This is the
+    host oracle the golden suite pins bit-exactly.
 
 ``"device"``
     The batched event engine (``repro.kernels.event_engine``): per-event
@@ -36,25 +26,21 @@ Interchangeable backends (the ``net=`` engine flag):
     are reconstructed on the fly from each slot's cached ``(rate, eta)``
     pair, every slot is re-rated, and a running-min over the new etas
     yields the next NET wake-up. Per-event work is O(1) regardless of
-    how many transfers are in flight (the saturated-backlog pathology of
-    the incremental backend), at the price of ulp-level drift: the
-    reconstruction ``rate * (eta - now)`` rounds differently from the
-    stepwise ``rem -= rate * dt`` integration, so the device engine is
-    pinned to the numpy oracle by *tolerance* goldens
-    (``tests/golden_tolerance.json``), not the bit-exact suite.
-    ``"device-interpret"`` runs the same flush through the Pallas
-    interpreter (slow; bit-identical to the ``"device"`` CPU route by the
-    kernel's oracle-identity contract). On TPU the chip picks each
-    slot's least fair share by its float64 rank and the host does the
-    flush's arithmetic in float64, so ``"device"`` is bit-identical to
-    its CPU route there too.
+    how many transfers are in flight, at the price of ulp-level drift:
+    the reconstruction ``rate * (eta - now)`` rounds differently from
+    the stepwise ``rem -= rate * dt`` integration, so the device engine
+    is pinned to the numpy oracle by *tolerance* goldens
+    (``tests/golden_tolerance.json``), not the bit-exact suite. Rates
+    themselves are the same pure function of link occupancy on every
+    route. Off the TPU the flush runs the float64 oracle over the dirty
+    neighborhood; on the TPU the compiled kernel flushes the whole slot
+    array: the chip picks each slot's least fair share by its float64
+    rank and the host does the arithmetic in float64, so each flush is
+    bit-identical to the oracle over the same slots.
 
-On CPU (oracle and interpret routes) the numpy and pallas backends return
-identical results on identical histories; the golden suite pins this
-(``tests/test_golden_metrics.py``). The *compiled* TPU kernel computes in
-float32 (TPUs have no f64), so on TPU ``net="pallas"`` is an approximate
-backend — rates drift at the 1e-7 relative level — and its bit-identity
-contract applies to the CPU routes only.
+``"device-interpret"``
+    The TPU's whole-array flush under the Pallas interpreter (slow): the
+    chip's route, rehearsed on the host.
 """
 
 from __future__ import annotations
@@ -71,8 +57,7 @@ from .topology import GridTopology
 # starve: eta increments below the clock's ulp make dt == 0 forever.
 _DONE_EPS = 1.0
 
-BACKENDS = ("numpy", "pallas", "pallas-interpret", "device",
-            "device-interpret")
+BACKENDS = ("numpy", "device", "device-interpret")
 
 
 class NetworkEngine:
@@ -84,22 +69,12 @@ class NetworkEngine:
                              f"(want one of {BACKENDS})")
         self.topology = topology
         self.backend = backend
-        self._ops_backend = {"pallas": "auto",
-                             "pallas-interpret": "interpret"}.get(backend)
         self._use_kernel = False
-        self.batched = backend in ("device", "device-interpret")
-        if backend == "pallas":
-            # resolve the route once: the compiled kernel op on TPU, the
-            # inline share-vector gather-min (same math) on CPU. The
-            # kernels package import is jax-free; ops pulls jax lazily.
-            from repro.kernels.net_rerate import net_rerate
-            import jax
-            self._use_kernel = jax.default_backend() == "tpu"
-            self._op = net_rerate
-        elif self.batched:
-            # same once-per-engine route resolution for the flush op: the
-            # compiled event_engine kernel on TPU, its float64 numpy
-            # oracle inline on CPU (no per-flush jax dispatch)
+        self.batched = backend != "numpy"
+        if self.batched:
+            # the flush route, resolved once per engine: the compiled
+            # event_engine kernel on TPU, its float64 numpy oracle inline
+            # on CPU (no per-flush jax dispatch)
             from repro.kernels.event_engine import (event_engine,
                                                     event_engine_core)
             self._flush_op = event_engine
@@ -149,7 +124,7 @@ class NetworkEngine:
         # asserts on these, so they are part of the engine contract):
         # rerate_calls — rerate() invocations; rerate_slots — slots
         # re-rated *synchronously inside rerate()* (the incremental
-        # routes' per-event member-union + eta-scan work; identically 0
+        # route's per-event member-union + eta-scan work; identically 0
         # on the batched backend, whose rerate only marks dirty links);
         # flush_passes / flush_slots — fused passes and the slots they
         # re-rated (at most one pass per drained instant); flush_kernel /
@@ -315,7 +290,7 @@ class NetworkEngine:
         this reconstructs ``rate * (eta - now)`` for slots the last flush
         rated — the exact formulation the flush pass itself uses — and
         reads the stored array for fresh/released slots; on the
-        incremental backends ``rem`` is already integrated and is
+        incremental route ``rem`` is already integrated and is
         returned as-is."""
         if not self.batched:
             return self.rem
@@ -378,22 +353,13 @@ class NetworkEngine:
         """Refresh rates after the occupancy of ``changed`` links moved;
         return the next completion time (None when nothing is draining).
 
-        All three routes compute the same pure function of link occupancy
-        and give identical results; they differ only in batching:
+        Every route computes the same pure function of link occupancy;
+        they differ only in batching:
 
         * numpy — incremental: re-rate the union of the changed links'
           member slots in one vectorized gather-min (small unions take a
           scalar fast path), then scan for the next completion on the
           host.
-        * pallas — the kernel's formulation of the same union batch. On
-          TPU it is a compiled ``net_rerate`` kernel call; on CPU the
-          identical expression runs inline in numpy (measurably faster
-          than the incremental baseline at the 10k-job scale point — see
-          ``results/BENCH_net.json``). Host next-completion scan.
-        * pallas-interpret — full-array: every slot (released rows are all
-          ``-1`` and rate 0) plus the next-completion scan in a single
-          kernel invocation under the Pallas interpreter. Slow; exists so
-          the bit-identity contract covers the kernel end to end.
         * device / device-interpret — deferred: record the changed link
           ids and mark the engine dirty, O(path length) per event no
           matter how many transfers are in flight; the simulator runs one
@@ -406,15 +372,6 @@ class NetworkEngine:
                 self._dirty_links[li] = None
             self.dirty = True
             return None
-        if self._ops_backend == "interpret":
-            if self.n_active == 0:
-                return None
-            from repro.kernels.net_rerate import net_rerate  # deferred: jax
-            self.stats["rerate_slots"] += self.n_active
-            rate, eta = net_rerate(self.path, self.rem, self.link_bw,
-                                   self.link_act, now, backend="interpret")
-            self.rate[:] = rate
-            return eta if np.isfinite(eta) else None
         # union the changed links' member slots first: a transfer whose
         # path crosses several changed links (source NIC + uplinks) is
         # re-rated once instead of once per link. Rates are pure functions
@@ -431,22 +388,13 @@ class NetworkEngine:
                 merged.update(self.members[li])
             slots = list(merged)
         self.stats["rerate_slots"] += len(slots)
-        if self._use_kernel:
-            if slots:
-                idx = np.fromiter(slots, np.intp, len(slots))
-                rate, _ = self._op(self.path[idx], self.rem[idx],
-                                   self.link_bw, self.link_act, now,
-                                   backend="pallas")
-                self.rate[idx] = rate
-        else:
-            # share vector hoisted once per event (occupancy is fixed
-            # while re-rating) for both CPU routes when the batch is big
-            # enough to amortize it: element-wise it is the exact same
-            # IEEE division as the per-slot gather, so rates are
-            # bit-identical either way.
-            share = (self.link_bw / np.maximum(1.0, self.link_act)
-                     if len(slots) > 4 else None)
-            self._rate_slots(slots, share)
+        # share vector hoisted once per event (occupancy is fixed while
+        # re-rating) when the batch is big enough to amortize it:
+        # element-wise it is the exact same IEEE division as the per-slot
+        # gather, so rates are bit-identical either way.
+        share = (self.link_bw / np.maximum(1.0, self.link_act)
+                 if len(slots) > 4 else None)
+        self._rate_slots(slots, share)
         if self.n_active == 0:
             return None
         live = self.rate > 0.0   # released slots are zeroed, so live ⊆ active
@@ -466,7 +414,7 @@ class NetworkEngine:
         pair: rates are pure functions of link occupancy, so they are
         still exact). The next completion then comes from one vectorized
         running-min over the cached eta array (released slots are ``inf``)
-        — O(capacity) *per instant*, where the incremental backends pay an
+        — O(capacity) *per instant*, where the incremental route pays an
         O(live) scan per *event*. On TPU (and under ``device-interpret``)
         the kernel instead sees the full slot array in a single call —
         subset gathers save nothing when the whole array is one fused

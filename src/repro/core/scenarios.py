@@ -79,9 +79,9 @@ class ScenarioSpec:
     ``slowdowns`` are literal ``(site, at, duration, factor)`` stragglers.
 
     *Engine* — scheduler / replication strategy / broker registry names,
-    the network-engine backend ``net`` (``numpy`` | ``pallas`` |
-    ``pallas-interpret`` | ``device`` | ``device-interpret`` | ``topmost``,
-    see :class:`repro.core.network.NetworkEngine`), the replication-economy
+    the network-engine backend ``net`` (``numpy`` | ``device`` |
+    ``device-interpret`` | ``topmost``, see
+    :class:`repro.core.network.NetworkEngine`), the replication-economy
     value-scoring backend ``econ`` + its period ``econ_interval_s``
     (``None`` arms the optimizer only for the access-aware strategies; see
     :mod:`repro.core.economy`) and the seeds to run (one simulation per
@@ -696,10 +696,10 @@ register_sweep(SweepSpec(
     name="contended_nets",
     base="deep_contended",
     axis="net",
-    values=("topmost", "numpy", "pallas"),
+    values=("topmost", "numpy"),
     description="Network-model fidelity grid: the legacy topmost-uplink "
-                "accounting vs the per-link path model vs the vectorized "
-                "re-rate backend on the mid-tier-contended tree.",
+                "accounting vs the per-link path model on the "
+                "mid-tier-contended tree.",
 ))
 
 register_sweep(SweepSpec(

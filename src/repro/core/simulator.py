@@ -13,8 +13,9 @@ source-side uplink path, so mid-tier congestion is real on deep trees).
 This reproduces GridSim's contention behaviour — the WAN uplink saturates
 under inter-region traffic — without a packet simulator. The fluid model
 lives in :class:`repro.core.network.NetworkEngine`; the ``net=`` flag picks
-its backend (``"numpy"`` incremental re-rating, ``"pallas"`` the vectorized
-kernel path, ``"topmost"`` the legacy single-uplink accounting).
+its backend (``"numpy"`` incremental re-rating, ``"device"`` one batched
+flush per event instant, on the chip where there is one, ``"topmost"``
+the legacy single-uplink accounting).
 
 Engine hot paths are built for 100k-job / 500-site scale (the ``grid_500``
 scenario is the pinned scale point):

@@ -2,11 +2,11 @@
 
 The ``net="device"`` engine backend defers every link-occupancy change
 within one event instant and then runs one fused pass: every slot is
-re-rated (min of per-link fair shares along its path, as in
-:mod:`repro.kernels.net_rerate`), remaining bytes are reconstructed from
-the cached ``(rate, eta)`` pair, and a running-min over the new etas
-yields the next NET wake-up — one device call per drained instant
-instead of one per event.
+re-rated (min of per-link fair shares along its path, as the
+incremental ``net="numpy"`` route computes it), remaining bytes are
+reconstructed from the cached ``(rate, eta)`` pair, and a running-min
+over the new etas yields the next NET wake-up — one device call per
+drained instant instead of one per event.
 
 The device picks each slot's least share by rank, and the host does the
 arithmetic in float64. Fair shares (``bandwidth / max(1, active)``) are
@@ -22,12 +22,12 @@ that float64 keeps tied stay tied. (A float32 flush split them: the
 chip's float32 division is off by up to 2 ulps, and float32 rounding
 alone splits ties of a long run.)
 
-Layout matches ``net_rerate``: the ranks carry a sentinel at index 0,
-past every real rank, where the path matrix's ``-1`` padding lands once
-every id is shifted by one; the jitted wrapper gathers them into a
-``(max_links, slots)`` plane (a 1-D gather inside the kernel does not
-lower on TPU); slots ride the lanes (padded to a lane multiple) and the
-small level axis the sublanes. One program sees the whole batch.
+Layout: the ranks carry a sentinel at index 0, past every real rank,
+where the path matrix's ``-1`` padding lands once every id is shifted
+by one; the jitted wrapper gathers them into a ``(max_links, slots)``
+plane (a 1-D gather inside the kernel does not lower on TPU); slots
+ride the lanes (padded to a lane multiple) and the small level axis
+the sublanes. One program sees the whole batch.
 
 A flush crosses between host and device once each way. The engine's
 route (:mod:`.ops`) builds both inputs of :func:`_flush_call` in numpy
@@ -35,10 +35,8 @@ route (:mod:`.ops`) builds both inputs of :func:`_flush_call` in numpy
 ranks) and hands them to the one program — gather, kernel — whose
 dispatch moves them to the device (no ``jax.device_put``); it copies
 the ``(1, slots)`` int32 output back, which :func:`host_rates` turns
-into float64 rates of the real slots.
-:func:`event_engine_kernel` is the whole flush for a traced caller (the
-jaxpr audit, the TPU compile test): the same program, with the shares,
-ranks and settle computed in jax in ``rem``'s dtype.
+into float64 rates of the real slots. :func:`_flush_call` is also
+what the jaxpr audit traces and the TPU compile test compiles.
 """
 
 from __future__ import annotations
@@ -123,44 +121,3 @@ def host_rates(least: np.ndarray, slots: int, table: np.ndarray):
     new rates of the ``slots`` real slots (``inf`` where a slot has no
     link, which ``ref.settle`` zeroes)."""
     return table[least[0, :slots]]
-
-
-def kernel_inputs(path, link_bw, link_act, dtype):
-    """:func:`host_inputs` in jax, for a traced caller: the same layout,
-    the shares and table in ``dtype``."""
-    slots, levels = path.shape
-    s_pad, l_pad = _padded(slots, levels)
-    path_t = jnp.pad(jnp.asarray(path, jnp.int32).T,
-                     ((0, l_pad - levels), (0, s_pad - slots)),
-                     constant_values=-1)
-    share = jnp.asarray(link_bw, dtype) / jnp.maximum(
-        1.0, jnp.asarray(link_act, dtype))
-    table = jnp.sort(share)
-    ranks = jnp.concatenate([
-        jnp.full((1,), share.shape[0], jnp.int32),
-        jnp.searchsorted(table, share, side="left").astype(jnp.int32)])
-    return (path_t, ranks), jnp.concatenate(
-        [table, jnp.full((1,), jnp.inf, dtype)])
-
-
-def event_engine_kernel(path, rem, rate, eta, link_bw, link_act, now, *,
-                        interpret: bool = False):
-    """Same contract as :func:`..ref.event_engine_ref`: the device's
-    least-rank program, with the shares, ranks and settle traced in jax.
-    ``path`` is ``(slots, max_links)`` (-1 padded); dtypes follow ``rem``
-    (float64 under x64, bit-identical to the oracle)."""
-    dtype = jnp.asarray(rem).dtype
-    slots = path.shape[0]
-    inputs, table = kernel_inputs(path, link_bw, link_act, dtype)
-    rate_new = table[_flush_call(*inputs, interpret=interpret)[0, :slots]]
-    rate_new = jnp.where(rate_new < jnp.inf, rate_new, 0.0)
-    rate_old = jnp.asarray(rate, dtype)
-    carried = rate_old > 0.0
-    # mask dead slots' inf etas before the multiply (no 0*inf NaNs)
-    eta_c = jnp.where(carried, jnp.asarray(eta, dtype), 0.0)
-    rem_now = jnp.maximum(jnp.where(carried, rate_old * (eta_c - now),
-                                    jnp.asarray(rem, dtype)), 0.0)
-    live = rate_new > 0.0
-    eta_new = jnp.where(live, now + rem_now / jnp.where(live, rate_new, 1.0),
-                        jnp.inf)
-    return rem_now, rate_new, eta_new, jnp.min(eta_new, initial=jnp.inf)

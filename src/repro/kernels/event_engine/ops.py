@@ -1,8 +1,8 @@
 """Public wrapper for the event-engine flush (pallas / interpret / numpy).
 
-Like :mod:`repro.kernels.net_rerate`, this op is called from the
-discrete-event loop (host code, once per drained event instant), so the
-wrapper returns host numpy values and picks the backend per call:
+This op is called from the discrete-event loop (host code, once per
+drained event instant), so the wrapper returns host numpy values and
+picks the backend per call:
 
   * ``"auto"``   — the compiled Pallas kernel on TPU; the float64 numpy
     oracle on CPU (no per-instant jax dispatch overhead). This is what

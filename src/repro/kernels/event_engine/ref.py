@@ -8,7 +8,7 @@ instant by the ``net="device"`` engine backend instead of once per event:
    never integrates ``rem`` on the host between flushes;
 2. re-rate every slot: min over its link path of
    ``bandwidth / max(1, active)`` (identical to the incremental numpy
-   backend and to :mod:`repro.kernels.net_rerate`);
+   route);
 3. recompute every slot's completion eta and reduce to the earliest one,
    which becomes the next NET wake-up.
 
@@ -20,8 +20,8 @@ engine — it is pinned by the tolerance-golden contract
 (``tests/golden_tolerance.json``) instead. Within the device route itself
 the chip only picks each slot's least share by rank (``kernel.py``) and
 :func:`settle` does the arithmetic on the host, so the route is
-bit-identical to this oracle on any chip; the traced kernel under x64
-is too — that contract the jaxpr auditor enforces.
+bit-identical to this oracle on any chip (``tests/test_kernels.py``
+holds it there, on the interpret route).
 """
 
 from __future__ import annotations

@@ -1,11 +1,11 @@
 """Kernel registry spec — the uniform shape every kernel package exports.
 
-Each package under :mod:`repro.kernels` (``net_rerate``, ``st_cost``,
-``value_score``, ``selective_scan``, ``flash_attention``) exposes a
-module-level ``SPEC: KernelSpec`` in its ``__init__``. The spec is the
-machine-readable contract the jaxpr auditor (:mod:`repro.analysis`)
-enforces for *every* kernel instead of the old one-off ``st_cost``
-shape-guard test:
+Each package under :mod:`repro.kernels` (``event_engine``, ``st_cost``,
+``strategy_plan``, ``value_score``, ``selective_scan``,
+``flash_attention``) exposes a module-level ``SPEC: KernelSpec`` in its
+``__init__``. The spec is the machine-readable contract the jaxpr
+auditor (:mod:`repro.analysis`) enforces for *every* kernel instead of
+the old one-off ``st_cost`` shape-guard test:
 
 * ``max_rank`` — structural rank cap on every intermediate aval in the
   traced jaxpr. For the sim kernels this is 2 (the whole point of the
@@ -18,12 +18,14 @@ shape-guard test:
   in the jaxpr (pallas bodies included) the auditor sums the aval bytes
   of its operands and results; the max over equations must stay under
   budget at the spec's representative audit shapes.
-* ``make_inputs`` — builds the representative-shape float32 numpy inputs
-  the audit traces at (plus the kernel's static kwargs).
+* ``make_inputs`` — builds the representative-shape numpy inputs the
+  audit traces at (float32; int32 where the operand is an index or a
+  rank), plus the kernel's static kwargs.
 * ``make_small_inputs`` — optional small-shape inputs for the runtime
   oracle checks (float64 oracle dtype + x64-interpret bit-identity).
-  Only the sim kernels carry these: their refs are pure-numpy oracles
-  (not traceable), so dtype discipline is checked by execution.
+  Only sim kernels whose entry point shares its oracle's signature
+  carry these: their refs are pure-numpy oracles (not traceable), so
+  dtype discipline is checked by execution.
 * ``arg_units`` / ``out_units`` — per-operand dimension signature in the
   vocabulary of :mod:`repro.analysis.units` (``bytes``, ``bytes_per_s``,
   ``sim_seconds``, ``count``, ``score``; model-kernel tensors are
@@ -33,7 +35,8 @@ shape-guard test:
 
 This module is imported by every kernel ``__init__`` and therefore MUST
 stay jax-free (the DES engine imports kernel packages on hosts without
-jax installed).
+jax installed); an input builder that needs a kernel's own host staging
+imports it when it is called.
 """
 
 from __future__ import annotations
@@ -106,41 +109,21 @@ class KernelSpec:
 # ---------------------------------------------------------------------------
 
 
-def _net_rerate_inputs(slots: int, links: int, levels: int,
-                       seed: int = 2) -> InputCase:
-    rng = np.random.default_rng(seed)
-    path = np.where(rng.random((slots, levels)) < 0.35, -1,
-                    rng.integers(0, links, (slots, levels)))
-    path[:, 0] = rng.integers(0, links, slots)
-    rem = (rng.random(slots) * 1e9).astype(np.float32)
-    bw = (rng.random(links) * 1e8 + 1e5).astype(np.float32)
-    act = rng.integers(0, 12, links).astype(np.float32)
-    return ((path.astype(np.int32), rem, bw, act, np.float32(321.5)), {})
-
-
 def _event_engine_inputs(slots: int, links: int, levels: int,
                          seed: int = 3) -> InputCase:
+    """The two operands of ``_flush_call`` as the engine's route builds
+    them on the host (``host_inputs``): the padded, transposed int32
+    path and the int32 share ranks."""
+    from .event_engine.kernel import host_inputs  # deferred: jax
     rng = np.random.default_rng(seed)
     path = np.where(rng.random((slots, levels)) < 0.35, -1,
                     rng.integers(0, links, (slots, levels)))
     path[:, 0] = rng.integers(0, links, slots)
-    # mix of slot states: ~1/4 released (all-padding path, zeroed state),
-    # ~1/3 freshly allocated (no cached rate yet, rem used verbatim), the
-    # rest carried over from a previous flush with a finite (rate, eta)
-    freed = rng.random(slots) < 0.25
-    path[freed] = -1
-    rem = (rng.random(slots) * 1e9).astype(np.float32)
-    rate = (rng.random(slots) * 1e7 + 1.0).astype(np.float32)
-    fresh = rng.random(slots) < 0.3
-    rate[fresh | freed] = 0.0
-    rem[freed] = 0.0
-    now = 321.5
-    eta = (now + rng.random(slots) * 5e3).astype(np.float32)
-    eta[rate == 0.0] = np.inf
-    bw = (rng.random(links) * 1e8 + 1e5).astype(np.float32)
-    act = rng.integers(0, 12, links).astype(np.float32)
-    return ((path.astype(np.int32), rem, rate, eta, bw, act,
-             np.float32(now)), {})
+    path[rng.random(slots) < 0.25] = -1       # released slots
+    bw = rng.random(links) * 1e8 + 1e5
+    act = rng.integers(0, 12, links).astype(np.float64)
+    inputs, _ = host_inputs(path, bw, act)
+    return inputs, {}
 
 
 def _value_score_inputs(sites: int, files: int, seed: int = 2) -> InputCase:
@@ -220,26 +203,16 @@ def _flash_attention_inputs(B: int, H: int, KV: int, Sq: int, Skv: int,
 #: for the per-kernel headroom math). Keep in sync with
 #: results/ANALYSIS_kernels.json (regenerated by ``python -m
 #: repro.analysis``).
-NET_RERATE_SPEC = KernelSpec(
-    name="net_rerate", module="repro.kernels.net_rerate",
-    kernel_attr="net_rerate_kernel", ref_attr="net_rerate_ref",
-    domain="sim", max_rank=2, budget_bytes=24_000,
-    make_inputs=lambda: _net_rerate_inputs(256, 60, 5),
-    make_small_inputs=lambda: _net_rerate_inputs(37, 23, 4),
-    arg_units=("count", "bytes", "bytes_per_s", "count", "sim_seconds"),
-    out_units=("bytes_per_s", "sim_seconds"),
-)
-
+#: The flush's device program, as the chip runs it: the float64 oracle
+#: identity of the whole route (stage, program, settle) is held at the op
+#: level by tests/test_kernels.py, so there is no small-input check here.
 EVENT_ENGINE_SPEC = KernelSpec(
     name="event_engine", module="repro.kernels.event_engine",
-    kernel_attr="event_engine_kernel", ref_attr="event_engine_ref",
+    kernel_attr="_flush_call", ref_attr="event_engine_ref",
     domain="sim", max_rank=2, budget_bytes=20_000,
     make_inputs=lambda: _event_engine_inputs(256, 60, 5),
-    make_small_inputs=lambda: _event_engine_inputs(37, 23, 4),
-    multi_output=True,
-    arg_units=("count", "bytes", "bytes_per_s", "sim_seconds",
-               "bytes_per_s", "count", "sim_seconds"),
-    out_units=("bytes", "bytes_per_s", "sim_seconds", "sim_seconds"),
+    arg_units=("count", "count"),
+    out_units=("count",),
 )
 
 ST_COST_SPEC = KernelSpec(

@@ -4,7 +4,7 @@
 calls once per dispatch batch; ``st_cost_ref`` the float64 oracle;
 ``st_cost_dense_ref`` the pre-blocked O(sites x files x sites)
 formulation kept only for the bit-identity tests. Importing this package
-pulls no jax — ``ops`` loads it lazily per call, like ``net_rerate``.
+pulls no jax — ``ops`` loads it lazily per call.
 """
 
 from ..spec import ST_COST_SPEC as SPEC

@@ -31,7 +31,7 @@ Bit-identity contract (pinned by ``tests/test_kernels.py``):
   so the per-job gathered sum below, the dense ``sum(axis=1)`` and the
   Pallas kernel's fori-loop accumulation all agree bit for bit;
 * the kernel under x64 interpret mode therefore reproduces this oracle
-  exactly — the same contract ``net_rerate`` / ``value_score`` pin.
+  exactly — the same contract ``value_score`` / ``strategy_plan`` pin.
 """
 
 from __future__ import annotations

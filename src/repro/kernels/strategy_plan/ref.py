@@ -32,7 +32,7 @@ the few pairs whose ``store_ok`` is false.
 Bit-identity contract (pinned by ``tests/test_kernels.py``): where /
 divide / compare are exact IEEE ops and the argmax is a first-occurrence
 running maximum, so the Pallas kernel under x64 interpret mode
-reproduces this oracle bit for bit — the same contract ``net_rerate`` /
+reproduces this oracle bit for bit — the same contract ``value_score`` /
 ``event_engine`` / ``st_cost`` pin.
 """
 

@@ -1,8 +1,7 @@
 """Public wrapper for the value-scoring pass (pallas / interpret / numpy).
 
-Like ``net_rerate``, this op is called from host code (the economy's
-periodic DES event), so it returns host numpy values and picks the route
-per call:
+This op is called from host code (the economy's periodic DES event),
+so it returns host numpy values and picks the route per call:
 
   * ``"auto"``   — the compiled Pallas kernel on TPU; the float64 numpy
     oracle on CPU (no per-event jax dispatch overhead, bit-identical to

@@ -18,9 +18,9 @@ Pairs with no external holder score 0 in both modes (nothing to buy).
 
 Max/divide are exact IEEE ops and the max-reduction is order-independent,
 so the Pallas kernel (``kernel.py``) run under x64 interpret mode is
-bit-identical to this oracle — the same contract ``net_rerate`` pins, here
-checked by ``tests/test_kernels.py`` and reachable end-to-end via the
-``econ="pallas-interpret"`` engine flag.
+bit-identical to this oracle — the same contract ``st_cost`` and
+``strategy_plan`` pin, here checked by ``tests/test_kernels.py`` and
+reachable end-to-end via the ``econ="pallas-interpret"`` engine flag.
 """
 
 from __future__ import annotations

@@ -68,8 +68,8 @@ def test_chip_smoke_phases_pass_on_interpret_routes(monkeypatch, capsys):
     monkeypatch.setattr(event_engine_pkg, "event_engine", interpret_op)
     spec = dataclasses.replace(get_scenario(chip_smoke.SCENARIO),
                                tier_fanouts=(2, 2, 3))
-    widths = chip_smoke.Widths(sites=13, links=20, levels=3, slots=300,
-                               files=40, files_evict=300, jobs=5, pairs=60)
+    widths = chip_smoke.Widths(sites=13, files=40, files_evict=300, jobs=5,
+                               pairs=60)
     failures = chip_smoke.smoke(spec, 60, widths, "interpret")
     assert failures == []
     records = [json.loads(line) for line in capsys.readouterr().out.splitlines()
@@ -79,9 +79,8 @@ def test_chip_smoke_phases_pass_on_interpret_routes(monkeypatch, capsys):
     assert device_run["net_stats"]["flush_kernel"] > 0
     assert device_run["net_stats"]["flush_host"] == 0
     kernels = [r["kernel"] for r in records if r["phase"] == "kernel"]
-    assert kernels == ["net_rerate", "st_cost", "strategy_plan",
-                       "value_score[cost]", "value_score[plain]",
-                       "value_score[cost]"]
+    assert kernels == ["st_cost", "strategy_plan", "value_score[cost]",
+                       "value_score[plain]", "value_score[cost]"]
     assert all(r["ok"] for r in records if r["phase"] == "kernel")
 
 

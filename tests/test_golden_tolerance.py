@@ -2,7 +2,7 @@
 
 Two-tier golden contract (docs/ARCHITECTURE.md):
 
-* tier 1 — the incremental numpy/pallas backends are pinned *bit-exactly*
+* tier 1 — the incremental numpy backend is pinned *bit-exactly*
   by tests/test_golden_metrics.py against ``golden_metrics.json``;
 * tier 2 — the batched ``device`` engine reconstructs remaining bytes
   from cached completion times (``rate * (eta - now)``) instead of

@@ -9,7 +9,6 @@ import pytest
 from repro.kernels.event_engine import event_engine, event_engine_ref
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import flash_attention_ref
-from repro.kernels.net_rerate import net_rerate, net_rerate_ref
 from repro.kernels.selective_scan.kernel import selective_scan_kernel
 from repro.kernels.selective_scan.ref import selective_scan_ref
 from repro.kernels.st_cost import st_cost, st_cost_dense_ref, st_cost_ref
@@ -91,36 +90,6 @@ def test_selective_scan_matches_oracle(Bz, S, Di, N, chunk, bd, dtype):
                                atol=TOL[dtype] * 10, rtol=TOL[dtype] * 10)
 
 
-def _net_rerate_case(seed, slots, links, levels):
-    """Random but realistic re-rate inputs: every slot crosses a NIC-like
-    first link plus 0..levels-1 uplinks."""
-    rng = np.random.default_rng(seed)
-    path = np.where(rng.random((slots, levels)) < 0.35, -1,
-                    rng.integers(0, links, (slots, levels)))
-    path[:, 0] = rng.integers(0, links, slots)
-    rem = rng.random(slots) * 1e9
-    bw = rng.random(links) * 1e8 + 1e5
-    act = rng.integers(0, 12, links).astype(float)
-    return path, rem, bw, act
-
-
-@pytest.mark.parametrize("seed,slots,links,levels", [
-    (0, 1, 4, 2),            # single transfer, two-level shape
-    (1, 37, 23, 4),          # ragged (pads to lane/sublane multiples)
-    (2, 256, 60, 5),         # deep 5-tier path shape
-    (3, 1000, 500, 3),       # wide link space
-])
-def test_net_rerate_interpret_matches_oracle(seed, slots, links, levels):
-    """The Pallas re-rate kernel under x64 interpret mode is *bit-identical*
-    to the float64 numpy oracle (divide/min are exact IEEE ops) — the same
-    contract the golden-metrics suite pins end-to-end."""
-    path, rem, bw, act = _net_rerate_case(seed, slots, links, levels)
-    rate_ref, eta_ref = net_rerate_ref(path, rem, bw, act, now=321.5)
-    rate_k, eta_k = net_rerate(path, rem, bw, act, 321.5, backend="interpret")
-    assert np.array_equal(rate_k, rate_ref)
-    assert eta_k == eta_ref
-
-
 def _event_engine_case(seed, slots, links, levels):
     """Mixed slot-lifecycle flush inputs: ~1/4 released (all-hole path,
     zeroed state), ~1/3 freshly allocated (no cached rate — rem is read
@@ -153,9 +122,9 @@ def _event_engine_case(seed, slots, links, levels):
 def test_event_engine_interpret_matches_oracle(seed, slots, links, levels):
     """The fused event-engine flush kernel (share -> gather-min re-rate ->
     eta reconstruction -> running-min next completion) under x64
-    interpret mode is *bit-identical* to the float64 numpy oracle — the
-    net_rerate contract extended to the batched engine's once-per-instant
-    pass that golden_tolerance.json pins end-to-end."""
+    interpret mode is *bit-identical* to the float64 numpy oracle: the
+    batched engine's once-per-instant pass, whose runs
+    golden_tolerance.json pins end-to-end."""
     path, rem, rate, eta, bw, act = _event_engine_case(seed, slots, links,
                                                        levels)
     ref = event_engine_ref(path, rem, rate, eta, bw, act, 321.5)
@@ -184,13 +153,12 @@ def test_event_engine_all_released_returns_inf():
 @pytest.mark.parametrize("slots", [0, 1, 37, 64, 128, 129])
 def test_event_engine_host_inputs_layout(slots):
     """The flush's inputs built in numpy on the host are the kernel's
-    layout, as the traced wrapper builds it in jax: the path transposed
-    so slots ride the lanes, levels padded to 8 and slots to a multiple
+    layout: the path transposed so slots ride the lanes, levels padded to 8 and slots to a multiple
     of 128 (one lane group at least) with -1 ids, then the int32 share
     ranks behind the padding's sentinel, whose table maps each rank back
     to its float64 share. The interpret route over them still matches
     the oracle."""
-    from repro.kernels.event_engine.kernel import host_inputs, kernel_inputs
+    from repro.kernels.event_engine.kernel import host_inputs
     links, levels = 23, 4
     path, rem, rate, eta, bw, act = _event_engine_case(slots, slots, links,
                                                        levels)
@@ -208,11 +176,6 @@ def test_event_engine_host_inputs_layout(slots):
     assert np.array_equal(np.argsort(ranks[1:], kind="stable"),
                           np.argsort(share, kind="stable"))
     assert table[links] == np.inf
-    (t_path, t_ranks), t_table = kernel_inputs(path, bw, act, jnp.float32)
-    assert np.array_equal(np.asarray(t_path), path_t)
-    assert np.array_equal(np.asarray(t_ranks),
-                          host_inputs(path, bw.astype(np.float32),
-                                      act)[0][1])
     ref = event_engine_ref(path, rem, rate, eta, bw, act, 321.5)
     out = event_engine(path, rem, rate, eta, bw, act, 321.5,
                        backend="interpret")
@@ -321,34 +284,6 @@ def test_event_engine_warm_flush_adds_no_compile(slots):
     put = np.asarray(_flush_call(*jax.device_put(inputs), interpret=True))
     assert on_host.dtype == put.dtype == np.int32
     assert np.array_equal(on_host, put)
-
-
-def test_net_rerate_auto_backend_on_cpu_is_exact():
-    """backend='auto' off-TPU routes to the float64 oracle — the fast path
-    the net='pallas' engine uses per event on this container."""
-    path, rem, bw, act = _net_rerate_case(7, 64, 30, 3)
-    rate_ref, eta_ref = net_rerate_ref(path, rem, bw, act, 0.0)
-    rate_a, eta_a = net_rerate(path, rem, bw, act, 0.0, backend="auto")
-    assert np.array_equal(rate_a, rate_ref)
-    assert eta_a == eta_ref
-
-
-def test_net_rerate_empty_and_padding_rows():
-    rate, eta = net_rerate_ref(np.zeros((0, 3), int), np.zeros(0),
-                               np.ones(4), np.zeros(4), 5.0)
-    assert rate.shape == (0,) and eta == float("inf")
-    # an all-padding row gets rate 0 and never drives the eta scan
-    path = np.array([[0, -1], [-1, -1]])
-    rate, eta = net_rerate_ref(path, np.array([10.0, 10.0]),
-                               np.array([2.0]), np.array([1.0]), 1.0)
-    assert rate[1] == 0.0
-    assert eta == pytest.approx(1.0 + 10.0 / 2.0)
-
-
-def test_net_rerate_rejects_unknown_backend():
-    with pytest.raises(ValueError, match="backend"):
-        net_rerate(np.zeros((1, 1), int), np.ones(1), np.ones(1),
-                   np.ones(1), 0.0, backend="cuda")
 
 
 def _value_score_case(seed, sites, files):

@@ -1,6 +1,7 @@
 """Path-contention NetworkEngine: uplink-path topology queries on deep
-trees, the hand-computed min-over-path contention fixture, backend
-equivalence (numpy vs pallas), and the legacy topmost-model divergence."""
+trees, the hand-computed min-over-path contention fixture on every route,
+rate agreement of the batched routes with the incremental one, and the
+legacy topmost-model divergence."""
 
 import dataclasses
 import types
@@ -96,8 +97,15 @@ def test_path_model_validation():
         _topo((2, 2), (10.0,), path_model="bogus")
 
 
+def _settle(net, changed, now):
+    """Re-rate after ``changed`` links moved and, on the batched routes,
+    run the instant's flush; return the next completion time."""
+    eta = net.rerate(changed, now)
+    return net.flush(now) if net.batched else eta
+
+
 # -- the 3-transfer mid-tier contention fixture -----------------------------
-@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+@pytest.mark.parametrize("backend", BACKENDS)
 def test_three_transfer_min_over_path(backend):
     """Hand-computed fair shares on a (2,2,2) tree: NIC 100 B/s, cluster
     uplinks 50, group uplinks 10 (ids: cluster c -> 8+c, group g -> 8+2+g).
@@ -110,26 +118,27 @@ def test_three_transfer_min_over_path(backend):
       t1 = min(100/2, 50/1, 10/2) = 5      (mid-tier bound through-traffic)
       t2 = min(100/1, 10/2)       = 5
       t3 = 100/2                  = 50
-    The legacy topmost model would rate t1 = min(100/2, 50/1) = 50."""
+    The legacy topmost model would rate t1 = min(100/2, 50/1) = 50.
+    The batched routes flush after every event, one instant each."""
     topo = _topo((2, 2, 2), (50.0, 10.0))
     net = NetworkEngine(topo, backend=backend)
     slots = {}
     for name, (src, dst) in {"t1": (0, 6), "t2": (1, 2), "t3": (0, 1)}.items():
         tr = types.SimpleNamespace(slot=-1)
         net.alloc(tr, 1e6, topo.link_ids_for(src, dst))
-        net.rerate(topo.link_ids_for(src, dst), 0.0)
+        _settle(net, topo.link_ids_for(src, dst), 0.0)
         slots[name] = tr.slot
     assert net.rate[slots["t1"]] == pytest.approx(5.0)
     assert net.rate[slots["t2"]] == pytest.approx(5.0)
     assert net.rate[slots["t3"]] == pytest.approx(50.0)
     # eta scan: smallest rem/rate wins
-    assert net.rerate((), 0.0) == pytest.approx(1e6 / 50.0)
+    assert _settle(net, (), 0.0) == pytest.approx(1e6 / 50.0)
 
     legacy = _topo((2, 2, 2), (50.0, 10.0), path_model="topmost")
     lnet = NetworkEngine(legacy, backend=backend)
     tr = types.SimpleNamespace(slot=-1)
     lnet.alloc(tr, 1e6, legacy.link_ids_for(0, 6))
-    lnet.rerate(legacy.link_ids_for(0, 6), 0.0)
+    _settle(lnet, legacy.link_ids_for(0, 6), 0.0)
     assert lnet.rate[tr.slot] == pytest.approx(50.0)
 
 
@@ -258,7 +267,10 @@ def test_unknown_backend_rejected():
     with pytest.raises(ValueError, match="net engine"):
         run_experiment(GridConfig(n_regions=2, sites_per_region=2), n_jobs=1,
                        net="fortran")
-    assert "numpy" in BACKENDS and "pallas" in BACKENDS
+    assert "numpy" in BACKENDS and "pallas" not in BACKENDS
+    with pytest.raises(ValueError, match="net engine"):
+        run_experiment(GridConfig(n_regions=2, sites_per_region=2), n_jobs=1,
+                       net="pallas")
 
 
 def test_topmost_refuses_full_path_topology():
@@ -277,30 +289,63 @@ def test_topmost_refuses_full_path_topology():
 
 # -- backend equivalence and fidelity divergence ----------------------------
 def test_two_level_backends_bit_identical():
-    """On two-level grids all engine flags (numpy / pallas / topmost) must
-    produce the same floats — the path is {NIC, region uplink} under every
-    model."""
+    """On two-level grids the incremental path model and the topmost
+    model must produce the same floats — the path is {NIC, region uplink}
+    under both."""
     cfg = GridConfig(n_regions=2, sites_per_region=4)
     base = run_experiment(cfg, strategy="hrs", n_jobs=60, net="numpy")
-    for net in ("pallas", "topmost"):
-        r = run_experiment(cfg, strategy="hrs", n_jobs=60, net=net)
-        assert r.avg_job_time == base.avg_job_time, net
-        assert r.avg_inter_comms == base.avg_inter_comms, net
-        assert r.total_wan_gb == base.total_wan_gb, net
-        assert r.makespan == base.makespan, net
+    r = run_experiment(cfg, strategy="hrs", n_jobs=60, net="topmost")
+    assert r.avg_job_time == base.avg_job_time
+    assert r.avg_inter_comms == base.avg_inter_comms
+    assert r.total_wan_gb == base.total_wan_gb
+    assert r.makespan == base.makespan
 
 
-def test_deep_tree_backends_bit_identical():
-    """numpy incremental vs pallas full-recompute agree bit-for-bit on a
-    deep tree too (same pure function of link occupancy)."""
-    mbps = 1e6 / 8
-    cfg = GridConfig(tier_fanouts=(3, 3, 6),
-                     uplink_bandwidths=(100 * mbps, 10 * mbps))
-    a = run_experiment(cfg, strategy="hrs", n_jobs=60, net="numpy")
-    b = run_experiment(cfg, strategy="hrs", n_jobs=60, net="pallas")
-    assert a.avg_job_time == b.avg_job_time
-    assert a.avg_inter_comms == b.avg_inter_comms
-    assert a.makespan == b.makespan
+@pytest.mark.parametrize("backend", ["device", "device-interpret"])
+@pytest.mark.parametrize("scenario", ["paper_baseline", "deep_4tier",
+                                      "deep_5tier", "deep_contended"])
+def test_batched_routes_rate_like_the_incremental_route(scenario, backend):
+    """A seeded history of 120 allocs and releases, one instant per step,
+    fed to a batched engine and to the incremental ``numpy`` engine on
+    the scenario's topology: after every step each live slot carries the
+    same rate on both, bit for bit. Rates are a pure function of link
+    occupancy on every route (remaining bytes and etas are not compared:
+    the batched routes rebuild them by design, under the tolerance
+    goldens)."""
+    import numpy as np
+
+    from repro.core import SCENARIOS, build_topology, to_grid_config
+    cfg = to_grid_config(SCENARIOS[scenario])
+    ref = NetworkEngine(build_topology(cfg), backend="numpy")
+    net = NetworkEngine(build_topology(cfg), backend=backend)
+    rng = np.random.default_rng(17)
+    n_sites, now = ref.topology.n_sites, 0.0
+    live: list[tuple] = []
+    for _ in range(120):
+        now += float(rng.random()) * 50.0
+        ref.advance(now)
+        net.advance(now)
+        if not live or rng.random() < 0.65:
+            src, dst = (int(x) for x in rng.integers(0, n_sites, 2))
+            links = ref.topology.link_ids_for(src, dst)
+            size = float(rng.random()) * 1e9 + 1e6
+            pair = (types.SimpleNamespace(slot=-1),
+                    types.SimpleNamespace(slot=-1))
+            ref.alloc(pair[0], size, links)
+            net.alloc(pair[1], size, links)
+            assert pair[0].slot == pair[1].slot
+            live.append(pair)
+            changed = links
+        else:
+            pair = live.pop(int(rng.integers(0, len(live))))
+            changed = ref.release(pair[0])
+            assert net.release(pair[1]) == changed
+        _settle(ref, changed, now)
+        _settle(net, changed, now)
+        slots = np.array([p[0].slot for p in live], np.intp)
+        assert np.array_equal(net.rate[slots], ref.rate[slots])
+        assert (ref.rate[slots] > 0.0).all()
+    assert net.stats["flush_passes"] == 120
 
 
 def test_deep_tree_full_path_diverges_from_topmost():

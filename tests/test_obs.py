@@ -316,8 +316,7 @@ def test_part_counts_ns_and_leaves_phases_alone():
         assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3
 
 
-@pytest.mark.parametrize("net", ["numpy", "pallas", "device",
-                                 "device-interpret"])
+@pytest.mark.parametrize("net", ["numpy", "device", "device-interpret"])
 def test_flush_parts_only_on_the_kernel_routes(net):
     """The four flush parts are timed on the kernel flush routes alone
     (here ``device-interpret``; ``device`` off the chip takes the host

@@ -99,7 +99,7 @@ def test_with_axis_vocabulary():
     base = SCENARIOS["paper_baseline"]
     assert with_axis(base, "n_jobs", 42).n_jobs == 42
     assert with_axis(base, "strategy", "economic").strategy == "economic"
-    assert with_axis(base, "net", "pallas").net == "pallas"
+    assert with_axis(base, "net", "device").net == "device"
     assert with_axis(base, "wan_mbps", 100.0).uplink_mbps[0] == 100.0
     with pytest.raises(ValueError, match="axis"):
         with_axis(base, "name", "x")

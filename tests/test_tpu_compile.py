@@ -62,17 +62,15 @@ def _compile(fn, *args, pallas=True):
     assert ("tpu_custom_call" in compiled.as_text()) == pallas
 
 
-def test_event_engine_compiles(shape):
-    from repro.kernels.event_engine.kernel import event_engine_kernel
-    s = shape((SLOTS,))
-    _compile(event_engine_kernel, shape((SLOTS, LEVELS), np.int32), s, s, s,
-             shape((LINKS,)), shape((LINKS,)), shape(()))
-
-
-def test_net_rerate_compiles(shape):
-    from repro.kernels.net_rerate.kernel import net_rerate_kernel
-    _compile(net_rerate_kernel, shape((SLOTS, LEVELS), np.int32),
-             shape((SLOTS,)), shape((LINKS,)), shape((LINKS,)), shape(()))
+@pytest.mark.parametrize("slots", [128, SLOTS])
+def test_event_engine_compiles(shape, slots):
+    """The flush's device program as the engine's route hands it its
+    operands: the padded ``(levels, slots)`` int32 path and the int32
+    share ranks behind the sentinel."""
+    from repro.kernels.event_engine.kernel import _flush_call, _padded
+    s_pad, l_pad = _padded(slots, LEVELS)
+    _compile(functools.partial(_flush_call, interpret=False),
+             shape((l_pad, s_pad), np.int32), shape((LINKS + 1,), np.int32))
 
 
 @pytest.mark.parametrize("files", [FILES, FILES_EVICT])

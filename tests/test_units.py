@@ -341,7 +341,7 @@ def test_list_rules_groups_by_family():
 def test_all_kernel_specs_declare_unit_signatures():
     from repro.kernels import registered_kernels
     specs = registered_kernels()
-    assert len(specs) == 7
+    assert len(specs) == 6
     for name, spec in specs.items():
         args, _ = spec.make_inputs()
         assert check_unit_signature(spec, len(args)), name
@@ -353,15 +353,11 @@ def test_sim_kernel_signatures_pinned():
     """The physical signatures of the DES kernels are load-bearing
     documentation — pin them."""
     from repro.kernels import get_kernel_spec
-    net = get_kernel_spec("net_rerate")
-    assert net.arg_units == ("count", "bytes", "bytes_per_s", "count",
-                             "sim_seconds")
-    assert net.out_units == ("bytes_per_s", "sim_seconds")
     st = get_kernel_spec("st_cost")
     assert st.out_units == ("sim_seconds",)
     ev = get_kernel_spec("event_engine")
-    assert ev.out_units == ("bytes", "bytes_per_s", "sim_seconds",
-                            "sim_seconds")
+    assert ev.arg_units == ("count", "count")       # link ids, share ranks
+    assert ev.out_units == ("count",)               # least rank
 
 
 def test_check_unit_signature_rejects_incomplete():
