@@ -32,11 +32,14 @@ Three routes (the ``net=`` engine flag):
     is pinned to the numpy oracle by *tolerance* goldens
     (``tests/golden_tolerance.json``), not the bit-exact suite. Rates
     themselves are the same pure function of link occupancy on every
-    route. Off the TPU the flush runs the float64 oracle over the dirty
-    neighborhood; on the TPU the compiled kernel flushes the whole slot
-    array: the chip picks each slot's least fair share by its float64
-    rank and the host does the arithmetic in float64, so each flush is
-    bit-identical to the oracle over the same slots.
+    route. A flush whose dirty neighborhood holds no live slot re-rates
+    nothing and returns the cached earliest eta, on every route. Off the
+    TPU the flush otherwise runs the float64 oracle over the dirty
+    neighborhood; on the TPU, when the neighborhood holds a live slot,
+    the compiled kernel flushes the whole slot array: the chip picks each
+    slot's least fair share by its float64 rank and the host does the
+    arithmetic in float64, so each flush is bit-identical to the oracle
+    over the same slots.
 
 ``"device-interpret"``
     The TPU's whole-array flush under the Pallas interpreter (slow): the
@@ -130,10 +133,13 @@ class NetworkEngine:
         # re-rated (at most one pass per drained instant); flush_kernel /
         # flush_host — passes that re-rated slots on the compiled
         # event_engine kernel / on the host (float64 oracle or the Pallas
-        # interpreter), so a run shows which route its flushes took.
+        # interpreter), so a run shows which route its flushes took;
+        # flush_skipped — passes that re-rated nothing (no live slot on a
+        # dirty link) and so made no crossing on any route.
         self.stats = {"rerate_calls": 0, "rerate_slots": 0,
                       "flush_passes": 0, "flush_slots": 0,
-                      "flush_kernel": 0, "flush_host": 0}
+                      "flush_kernel": 0, "flush_host": 0,
+                      "flush_skipped": 0}
         # the run's repro.obs probe (GridSimulator hands it over; None
         # when obs is off, and deep copies drop it): times the kernel
         # route's flush in parts
@@ -415,10 +421,15 @@ class NetworkEngine:
         still exact). The next completion then comes from one vectorized
         running-min over the cached eta array (released slots are ``inf``)
         — O(capacity) *per instant*, where the incremental route pays an
-        O(live) scan per *event*. On TPU (and under ``device-interpret``)
-        the kernel instead sees the full slot array in a single call —
-        subset gathers save nothing when the whole array is one fused
-        device pass — and its running-min output is used directly.
+        O(live) scan per *event*. When the neighborhood holds no live slot
+        (a release freed the last slot on every link it touched, or a NET
+        wake-up found no completion) the pass re-rates nothing on any
+        route: it returns the cached earliest eta, makes no crossing and
+        counts in ``stats["flush_skipped"]``. Otherwise, on TPU (and under
+        ``device-interpret``) the kernel sees the full slot array in a
+        single call — subset gathers save nothing when the whole array is
+        one fused device pass — and its running-min output is used
+        directly.
 
         Writes back the reconstructed ``rem``, the new ``rate`` and the
         new per-slot ``eta`` (so host readers — completions, the tie-race
@@ -429,11 +440,15 @@ class NetworkEngine:
         self.dirty = False
         self.last = now
         self.stats["flush_passes"] += 1
-        if self.n_active == 0:
-            self._dirty_links.clear()
-            return None
+        dirty, self._dirty_links = self._dirty_links, {}
+        if not any(self.members[li] for li in dirty):
+            # no live slot uses a link whose occupancy moved: every cached
+            # (rate, eta) pair is still exact, so the pass re-rates
+            # nothing and makes no crossing on any route
+            self.stats["flush_skipped"] += 1
+            eta_min = float(self.eta.min())
+            return eta_min if np.isfinite(eta_min) else None
         if self._use_kernel or self.backend == "device-interpret":
-            self._dirty_links.clear()
             self.stats["flush_slots"] += self.n_active
             self.stats["flush_kernel" if self._use_kernel
                        else "flush_host"] += 1
@@ -454,46 +469,43 @@ class NetworkEngine:
         # CPU route: the same fused pass (float64 oracle) over the dirty
         # neighborhood, then the running-min over the eta array
         merged: dict[int, None] = {}
-        for li in self._dirty_links:
+        for li in dirty:
             merged.update(self.members[li])
-        self._dirty_links.clear()
-        if merged:
-            self.stats["flush_slots"] += len(merged)
-            self.stats["flush_host"] += 1
-            if len(merged) <= 8:
-                # scalar fast path: same IEEE-double math as the ref pass
-                # (Python floats are f64), skipping the fancy-index
-                # gather/scatter overhead that dominates tiny unions
-                bw, act = self.link_bw, self.link_act
-                for s in merged:
-                    r_new = math.inf
-                    for li in self.path[s]:
-                        if li < 0:
-                            break
-                        a = act[li]
-                        sh = bw[li] / (a if a > 1.0 else 1.0)
-                        if sh < r_new:
-                            r_new = sh
-                    if math.isinf(r_new):   # all-padding row
-                        r_new = 0.0
-                    old_rate = self.rate[s]
-                    if old_rate > 0.0:
-                        rn = old_rate * (self.eta[s] - now)
-                    else:
-                        rn = self.rem[s]
-                    if rn < 0.0:
-                        rn = 0.0
-                    self.rem[s] = rn
-                    self.rate[s] = r_new
-                    if r_new > 0.0:
-                        e = now + rn / r_new
-                        self.eta[s] = e
-                        self.due[s] = e - _DONE_EPS / r_new
-                    else:
-                        self.eta[s] = np.inf
-                        self.due[s] = np.inf
-                eta_min = float(self.eta.min())
-                return eta_min if np.isfinite(eta_min) else None
+        self.stats["flush_slots"] += len(merged)
+        self.stats["flush_host"] += 1
+        if len(merged) <= 8:
+            # scalar fast path: same IEEE-double math as the ref pass
+            # (Python floats are f64), skipping the fancy-index
+            # gather/scatter overhead that dominates tiny unions
+            bw, act = self.link_bw, self.link_act
+            for s in merged:
+                r_new = math.inf
+                for li in self.path[s]:
+                    if li < 0:
+                        break
+                    a = act[li]
+                    sh = bw[li] / (a if a > 1.0 else 1.0)
+                    if sh < r_new:
+                        r_new = sh
+                if math.isinf(r_new):   # all-padding row
+                    r_new = 0.0
+                old_rate = self.rate[s]
+                if old_rate > 0.0:
+                    rn = old_rate * (self.eta[s] - now)
+                else:
+                    rn = self.rem[s]
+                if rn < 0.0:
+                    rn = 0.0
+                self.rem[s] = rn
+                self.rate[s] = r_new
+                if r_new > 0.0:
+                    e = now + rn / r_new
+                    self.eta[s] = e
+                    self.due[s] = e - _DONE_EPS / r_new
+                else:
+                    self.eta[s] = np.inf
+                    self.due[s] = np.inf
+        else:
             idx = np.fromiter(merged, np.intp, len(merged))
             rem_now, rate_new, eta_new, _ = self._flush_ref(
                 self.path[idx], self.rem[idx], self.rate[idx],

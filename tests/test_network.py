@@ -229,7 +229,8 @@ def test_engine_counters_surface_through_results():
     dev = run_experiment(cfg, n_jobs=80, net="device")   # batched device
     assert set(inc.net_stats) == {"rerate_calls", "rerate_slots",
                                   "flush_passes", "flush_slots",
-                                  "flush_kernel", "flush_host"}
+                                  "flush_kernel", "flush_host",
+                                  "flush_skipped"}
     # incremental engine: per-event union re-rates, never a fused flush
     assert inc.net_stats["rerate_slots"] > 0
     assert inc.net_stats["flush_passes"] == 0
@@ -242,6 +243,105 @@ def test_engine_counters_surface_through_results():
     assert 0 < dev.net_stats["flush_host"] <= dev.net_stats["flush_passes"]
     # both engines saw the same event stream
     assert dev.net_stats["rerate_calls"] == inc.net_stats["rerate_calls"]
+
+
+def _counting_crossings(monkeypatch) -> dict:
+    """Patch the flush program to count its calls (the chip's crossings)."""
+    from repro.kernels.event_engine import kernel
+    real_call = kernel._flush_call
+    calls = {"n": 0}
+
+    def call(*operands, **kwargs):
+        calls["n"] += 1
+        return real_call(*operands, **kwargs)
+
+    monkeypatch.setattr(kernel, "_flush_call", call)
+    return calls
+
+
+def _two_apart(backend):
+    """Two live transfers on disjoint links of a (2,2,2) tree, flushed:
+    ``a`` (0 -> 1, nic0 only, 1e6 B) and ``b`` (4 -> 5, nic4 only, 1e5 B,
+    the earlier completion)."""
+    topo = _topo((2, 2, 2), (50.0, 10.0))
+    net = NetworkEngine(topo, backend=backend)
+    trs = {}
+    for name, (src, dst, size) in {"a": (0, 1, 1e6),
+                                   "b": (4, 5, 1e5)}.items():
+        trs[name] = types.SimpleNamespace(slot=-1)
+        net.alloc(trs[name], size, topo.link_ids_for(src, dst))
+        net.rerate(topo.link_ids_for(src, dst), 0.0)
+    return net, trs, net.flush(0.0)
+
+
+@pytest.mark.parametrize("backend", ["device", "device-interpret"])
+@pytest.mark.parametrize("change", ["release_last_on_links", "net_wakeup"])
+def test_flush_of_an_empty_neighborhood_makes_no_crossing(
+        monkeypatch, change, backend):
+    """A pass whose dirty links hold no live slot re-rates nothing on any
+    route: no flush program runs, the slot state stays bit for bit, the
+    next completion is the one the pass before returned, and only
+    ``flush_skipped`` counts it."""
+    import numpy as np
+
+    net, trs, eta_before = _two_apart(backend)
+    assert eta_before == pytest.approx(1e5 / 100.0)
+    if change == "release_last_on_links":
+        changed = net.release(trs["a"])       # nic0 held only ``a``
+        assert not any(net.members[li] for li in changed)
+    else:
+        changed = ()                          # a NET wake-up, no completion
+    calls = _counting_crossings(monkeypatch)
+    state = {k: getattr(net, k).copy() for k in ("rem", "rate", "eta", "due")}
+    stats = dict(net.stats)
+    net.rerate(changed, 400.0)
+    eta = net.flush(400.0)
+    assert calls["n"] == 0
+    assert eta == eta_before
+    for k, v in state.items():
+        assert np.array_equal(getattr(net, k), v)
+    assert net.stats["flush_skipped"] == stats["flush_skipped"] + 1
+    assert net.stats["flush_passes"] == stats["flush_passes"] + 1
+    for k in ("flush_kernel", "flush_host", "flush_slots"):
+        assert net.stats[k] == stats[k]
+    assert not net.dirty and not net._dirty_links
+
+
+def test_flush_of_a_neighborhood_with_a_live_slot_crosses_once(monkeypatch):
+    """A release that leaves a live slot on a changed link still crosses,
+    once, over the whole slot array, and re-rates that slot."""
+    net, trs, _ = _two_apart("device-interpret")
+    c = types.SimpleNamespace(slot=-1)
+    links = net.topology.link_ids_for(0, 1)
+    net.alloc(c, 1e6, links)                  # shares nic0 with ``a``
+    net.rerate(links, 0.0)
+    net.flush(0.0)
+    assert net.rate[c.slot] == pytest.approx(50.0)
+    calls = _counting_crossings(monkeypatch)
+    stats = dict(net.stats)
+    net.rerate(net.release(trs["a"]), 400.0)
+    net.flush(400.0)
+    assert calls["n"] == 1
+    assert net.rate[c.slot] == pytest.approx(100.0)
+    assert net.stats["flush_host"] == stats["flush_host"] + 1
+    assert net.stats["flush_slots"] == stats["flush_slots"] + net.n_active
+    assert net.stats["flush_skipped"] == stats["flush_skipped"]
+
+
+def test_batched_routes_skip_the_same_passes_of_a_run():
+    """A Table 1-shaped world (4 x 13 sites, a 20-job burst into the
+    empty grid): the CPU route and the chip's route, rehearsed under the
+    interpreter, skip the same passes and re-rate on the same others."""
+    from repro.core import SCENARIOS
+    from repro.launch.experiments import run_spec
+    spec = dataclasses.replace(SCENARIOS["paper_baseline"], arrival_burst=20)
+    runs = {net: run_spec(dataclasses.replace(spec, net=net), seed=3,
+                          n_jobs=20) for net in ("device", "device-interpret")}
+    cpu, chip = (runs[n].net_stats for n in ("device", "device-interpret"))
+    assert cpu["flush_skipped"] > 0
+    for k in ("flush_passes", "flush_skipped", "flush_host", "rerate_calls"):
+        assert cpu[k] == chip[k]
+    assert cpu["flush_passes"] == cpu["flush_skipped"] + cpu["flush_host"]
 
 
 def test_engine_release_and_regrow():
